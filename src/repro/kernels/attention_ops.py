@@ -13,6 +13,11 @@ Selection order (``resolve_impl``):
      interpreter is correct but slow, so CPU CI stays on jnp unless a
      test opts in).
 
+No silent fallback: once the Pallas backend is chosen, a shape the
+compiled TPU kernel cannot take raises (:func:`require_compiled`) rather
+than quietly running the jnp reference — a run on the chip either uses
+the kernels or says why not.  Interpret mode takes any shape.
+
 ``flash_pallas`` is the Pallas twin of ``attention_ref.flash_reference``
 — same operand contract (pre-scaled q, sentinel positions, chunk-aligned
 padding), same custom-VJP residuals (out, m, l), so ``flash_attention``
@@ -36,13 +41,12 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def compiled_shape_ok(block: int) -> bool:
-    """Gate for the COMPILED (real-TPU) kernels: sub-8 / unaligned
-    sequence blocks produce sublane tiles Mosaic handles poorly (or not
-    at all), so hostile shapes fall back to the jnp reference on TPU.
-    Interpret mode has no such constraint — CPU parity tests exercise
-    the kernels at any block size."""
-    return _interpret() or (block >= 8 and block % 8 == 0)
+def require_compiled(ok: bool, what: str) -> None:
+    """Refuse, on TPU, a shape the compiled kernel cannot take."""
+    if not ok and not _interpret():
+        raise ValueError(
+            f"{what}: the compiled TPU kernel cannot take this shape; pass "
+            f"impl='jnp' to run the jnp reference instead")
 
 
 def resolve_impl(impl: Optional[str] = None) -> str:
@@ -72,6 +76,9 @@ def flash_pallas(q, k, v, qpos, kpos, window, chunk):
 
 
 def _flash_pallas_fwd_impl(q, k, v, qpos, kpos, window, chunk):
+    require_compiled(flash_kernel.compiles(chunk, q.shape[1], k.shape[1]),
+                     f"flash attention with chunk {chunk} over "
+                     f"{q.shape[1]} queries / {k.shape[1]} keys")
     outs, m, l = flash_kernel.forward(
         _to_bhsd(q), _to_bhsd(k), _to_bhsd(v),
         qpos.reshape(-1, 1), kpos.reshape(1, -1),
@@ -110,18 +117,25 @@ flash_pallas.defvjp(_flash_pallas_vjp_fwd, _flash_pallas_vjp_bwd)
 # decode
 # ---------------------------------------------------------------------------
 
+def _decode_block(length: int) -> int:
+    """Cache-length block for the ring kernels (one block in interpret
+    mode when no VMEM-safe divisor exists)."""
+    block = decode_kernel.pick_block(length)
+    if _interpret():
+        return block or length
+    require_compiled(block is not None
+                     and decode_kernel.compiles(block, length),
+                     f"decode over a {length}-slot cache (block {block})")
+    return block
+
+
 def decode_pallas(qf, k_cache, v_cache, kpos, qpos, *, window=None):
     """Fused single-token decode.  qf (B, KH, G, D) pre-scaled; caches in
     the native (B, L, KH, D/Dv) ring-buffer layout.  Returns
     (B, KH, G, Dv) fp32."""
-    block = decode_kernel.pick_block(k_cache.shape[1])
-    if block is None or not compiled_shape_ok(block):
-        # no VMEM-safe (or, compiled, sublane-aligned) block divides
-        return attention_ref.decode_attention_ref(
-            qf, k_cache, v_cache, kpos, qpos, window=window)
     return decode_kernel.decode(
-        qf, k_cache, v_cache, kpos, qpos.reshape(-1, 1).astype(jnp.int32),
-        window=window, block=block, interpret=_interpret())
+        qf, k_cache, v_cache, kpos, qpos.astype(jnp.int32), window=window,
+        block=_decode_block(k_cache.shape[1]), interpret=_interpret())
 
 
 def decode_q8_pallas(qf, k_codes, v_codes, k_scale, v_scale, kpos, qpos, *,
@@ -129,17 +143,12 @@ def decode_q8_pallas(qf, k_codes, v_codes, k_scale, v_scale, kpos, qpos, *,
     """Fused int8-cache decode; folds the absmax scales into the dots
     inside the kernel.  The (B, L, KH) fp16 scales are cast/transposed to
     (B, KH, L) fp32 here — they are D-times smaller than the codes."""
-    block = decode_kernel.pick_block(k_codes.shape[1])
-    if block is None or not compiled_shape_ok(block):
-        return attention_ref.decode_attention_q8_ref(
-            qf, k_codes, v_codes, k_scale, v_scale, kpos, qpos,
-            window=window)
     ks = k_scale.astype(jnp.float32).transpose(0, 2, 1)
     vs = v_scale.astype(jnp.float32).transpose(0, 2, 1)
     return decode_kernel.decode_q8(
-        qf, k_codes, v_codes, ks, vs, kpos,
-        qpos.reshape(-1, 1).astype(jnp.int32),
-        window=window, block=block, interpret=_interpret())
+        qf, k_codes, v_codes, ks, vs, kpos, qpos.astype(jnp.int32),
+        window=window, block=_decode_block(k_codes.shape[1]),
+        interpret=_interpret())
 
 
 def decode_paged_pallas(qf, k_pool, v_pool, pos_pool, page_table, qpos, *,
@@ -149,14 +158,9 @@ def decode_paged_pallas(qf, k_pool, v_pool, pos_pool, page_table, qpos, *,
     physical location — no gathered contiguous cache copy.  qf
     (S, KH, G, D) pre-scaled; pools (P, pg, KH, D/Dv); page_table (S, npp)
     with -1 for unallocated; qpos (S,).  Returns (S, KH, G, Dv) fp32."""
-    pg = k_pool.shape[1]
-    if not compiled_shape_ok(pg):
-        return attention_ref.decode_attention_paged_ref(
-            qf, k_pool, v_pool, pos_pool, page_table, qpos, window=window)
     return decode_kernel.decode_paged(
         qf, k_pool, v_pool, pos_pool, page_table.astype(jnp.int32),
-        qpos.reshape(-1, 1).astype(jnp.int32),
-        window=window, interpret=_interpret())
+        qpos.astype(jnp.int32), window=window, interpret=_interpret())
 
 
 def decode_paged_q8_pallas(qf, k_pool, v_pool, k_scale_pool, v_scale_pool,
@@ -165,14 +169,8 @@ def decode_paged_q8_pallas(qf, k_pool, v_pool, k_scale_pool, v_scale_pool,
     Scale pools arrive in the engine's native (P, pg, KH) fp16 layout and
     are cast/transposed to (P, KH, pg) fp32 here (D-times smaller than the
     codes)."""
-    pg = k_pool.shape[1]
-    if not compiled_shape_ok(pg):
-        return attention_ref.decode_attention_paged_q8_ref(
-            qf, k_pool, v_pool, k_scale_pool, v_scale_pool, pos_pool,
-            page_table, qpos, window=window)
     ks = k_scale_pool.astype(jnp.float32).transpose(0, 2, 1)
     vs = v_scale_pool.astype(jnp.float32).transpose(0, 2, 1)
     return decode_kernel.decode_paged_q8(
         qf, k_pool, v_pool, ks, vs, pos_pool, page_table.astype(jnp.int32),
-        qpos.reshape(-1, 1).astype(jnp.int32),
-        window=window, interpret=_interpret())
+        qpos.astype(jnp.int32), window=window, interpret=_interpret())

@@ -10,13 +10,17 @@ across the L sweep, and for the int8 cache fold the per-(token, head)
 absmax scales directly into the two dots, so no dequantized K/V tile
 ever exists outside VMEM.
 
-Grid: (B, KH, nL) with the cache-length axis innermost.  Caches keep the
-repo's native (B, L, KH, D) ring-buffer layout — blocks are strided
-(1, bL, 1, D) DMAs, squeezed to (bL, D) in VMEM.  Masking (empty slots,
-causality, sliding window) uses the runtime (kpos, qpos) vectors, and
-fully-masked blocks (outside the window / not yet written) are skipped
-with ``pl.when`` — the ring-buffer sweep degrades to O(window) work for
-long-context serving.
+Grid: (B, nL) with the cache-length axis innermost.  Caches keep the
+repo's native (B, L, KH, D) ring-buffer layout; each grid step DMAs one
+(bL, KH, D) block holding every KV head (the TPU tiling rule forbids a
+block of 1 on the KH axis, which is the second-minor one) and loops over
+the heads in VMEM.  The query position rides in as a scalar-prefetch
+operand (SMEM); the per-slot key positions arrive as (1, bL) lane
+vectors, so ``bL`` must be a multiple of 128 or the whole cache
+(:func:`compiles`).  Masking (empty slots, causality, sliding window)
+uses those runtime positions, and fully-masked blocks (outside the
+window / not yet written) are skipped with ``pl.when`` — the ring-buffer
+sweep degrades to O(window) work for long-context serving.
 
 Validated on CPU with interpret=True against attention_ref.
 """
@@ -35,29 +39,42 @@ _TRANS_B = (((1,), (1,)), ((), ()))
 _PLAIN = (((1,), (0,)), ((), ()))
 
 
-# Largest cache-length block the kernels will accept: a (bL, D=256) fp32
-# K tile at 2048 rows is 2 MiB — comfortably inside VMEM with V, scales
-# and scratch.  Lengths with no divisor <= MAX_BLOCK (e.g. large primes)
-# are rejected by pick_block and fall back to the jnp reference.
+# Largest cache-length block the kernels will accept.  Lengths with no
+# divisor <= MAX_BLOCK (e.g. large primes) are rejected by pick_block.
 MAX_BLOCK = 2048
 
 
 def pick_block(length: int, target: int = 512) -> Optional[int]:
     """VMEM-safe cache-length block: the largest divisor of ``length``
-    <= min(target, MAX_BLOCK), preferring sublane-aligned (multiple-of-8)
-    blocks.  Returns ``None`` when no reasonable block divides (e.g.
-    prime lengths beyond MAX_BLOCK) — callers fall back to the jnp
-    reference."""
+    <= min(target, MAX_BLOCK), preferring lane-aligned (multiple-of-128)
+    blocks, then sublane-aligned (multiple-of-8) ones.  Returns ``None``
+    when no reasonable block divides (e.g. prime lengths beyond
+    MAX_BLOCK)."""
     cap = min(target, MAX_BLOCK, length)
-    for cand in range(cap - cap % 8, 7, -8):  # aligned, largest first
-        if length % cand == 0:
-            return cand
+    for step in (128, 8):  # aligned, largest first
+        for cand in range(cap - cap % step, step - 1, -step):
+            if length % cand == 0:
+                return cand
     if length <= cap:
         return length  # odd-but-small ring buffers: one block
-    for cand in range(cap, 7, -1):  # unaligned beats falling back
+    for cand in range(cap, 7, -1):  # unaligned beats no block at all
         if length % cand == 0:
             return cand
     return None
+
+
+def compiles(block: int, length: int) -> bool:
+    """Whether the compiled TPU kernel takes a ``block`` of a ``length``
+    cache: the (1, bL) key-position and scale blocks put bL on the lane
+    axis, which must be a multiple of 128 or the whole axis."""
+    return length % block == 0 and (block % 128 == 0 or block == length)
+
+
+def paged_compiles(page_size: int) -> bool:
+    """Whether the compiled paged kernels take ``page_size``: a page block
+    spans the pool's trailing axes, so the tiling rule allows any size,
+    but a one-slot page leaves a (1, 1) mask Mosaic cannot broadcast."""
+    return page_size >= 2
 
 
 def _valid(kp, qp, window):
@@ -68,110 +85,124 @@ def _valid(kp, qp, window):
     return v
 
 
-def _online_update(s, v_blk, m_s, l_s, acc_s, p_scale=None):
-    """One online-softmax step: s (G, bL) masked scores, v_blk (bL, Dv);
-    ``p_scale`` (1, bL) folds the int8 V absmax scales into p before the
-    dot (the l normalizer keeps the unscaled p, matching the reference
-    softmax-then-scale order)."""
-    m_prev = m_s[...]  # (G, 1)
-    m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    p = jnp.exp(s - m_next)
-    corr = jnp.exp(m_prev - m_next)
-    l_s[...] = l_s[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-    m_s[...] = m_next
-    pv = p if p_scale is None else p * p_scale
-    pv = jax.lax.dot_general(pv.astype(v_blk.dtype), v_blk, _PLAIN,
-                             preferred_element_type=jnp.float32)
-    acc_s[...] = acc_s[...] * corr + pv
+def _init_state(m_s, l_s, acc_s):
+    m_s[...] = jnp.full_like(m_s, _NEG_INF)
+    l_s[...] = jnp.zeros_like(l_s)
+    acc_s[...] = jnp.zeros_like(acc_s)
 
 
-def _decode_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_s, l_s, acc_s, *, window, nl):
-    j = pl.program_id(2)
+def _finalize(o_ref, l_s, acc_s):
+    o_ref[0] = acc_s[...] / jnp.maximum(l_s[...], 1e-30)
+
+
+def _attend_heads(q_ref, k_ref, v_ref, valid, m_s, l_s, acc_s,
+                  ks_ref=None, vs_ref=None):
+    """One online-softmax step for every KV head of the block.
+
+    q_ref (1, KH, G, D) pre-scaled queries; k_ref/v_ref (1, bL, KH, D/Dv)
+    cache blocks (int8 codes when ``ks_ref``/``vs_ref`` hold the (1, KH,
+    bL) absmax scales); valid (1, bL).  The V scales fold into p before
+    the dot, while the l normalizer keeps the unscaled p, matching the
+    reference softmax-then-scale order."""
+    for h in range(k_ref.shape[2]):
+        q = q_ref[0, h]                           # (G, D)
+        k = k_ref[0, :, h, :].astype(q.dtype)     # (bL, D)
+        s = jax.lax.dot_general(q, k, _TRANS_B,
+                                preferred_element_type=jnp.float32)
+        if ks_ref is not None:
+            s = s * ks_ref[0, h:h + 1, :]
+        s = jnp.where(valid, s, _NEG_INF)
+        m_prev = m_s[h]                           # (G, 1)
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_next)
+        corr = jnp.exp(m_prev - m_next)
+        l_s[h] = l_s[h] * corr + jnp.sum(p, axis=1, keepdims=True)
+        m_s[h] = m_next
+        if vs_ref is not None:
+            p = p * vs_ref[0, h:h + 1, :]
+        v = v_ref[0, :, h, :].astype(q.dtype)     # (bL, Dv)
+        pv = jax.lax.dot_general(p.astype(q.dtype), v, _PLAIN,
+                                 preferred_element_type=jnp.float32)
+        acc_s[h] = acc_s[h] * corr + pv
+
+
+def _any(mask):
+    return jnp.max(mask.astype(jnp.int32)) > 0
+
+
+def _scratch(kh, g, dv):
+    return [pltpu.VMEM((kh, g, 1), jnp.float32),
+            pltpu.VMEM((kh, g, 1), jnp.float32),
+            pltpu.VMEM((kh, g, dv), jnp.float32)]
+
+
+# ---------------------------------------------------------------------------
+# contiguous ring-buffer caches (static generate path)
+# ---------------------------------------------------------------------------
+
+def _decode_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, *rest, window,
+                   nl, quantized):
+    if quantized:
+        ks_ref, vs_ref, o_ref, m_s, l_s, acc_s = rest
+    else:
+        ks_ref = vs_ref = None
+        o_ref, m_s, l_s, acc_s = rest
+    b = pl.program_id(0)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        m_s[...] = jnp.full_like(m_s, _NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
+        _init_state(m_s, l_s, acc_s)
 
-    qp = qpos_ref[...]  # (1, 1) int32
-    kp = kpos_ref[...]  # (1, bL) int32
-    valid = _valid(kp, qp, window)
+    valid = _valid(kpos_ref[0], qpos_ref[b], window)  # (1, bL)
 
-    @pl.when(jnp.any(valid))
+    @pl.when(_any(valid))
     def _compute():
-        q = q_ref[0, 0]          # (G, D), pre-scaled
-        k = k_ref[0, :, 0, :]    # (bL, D)
-        s = jax.lax.dot_general(q, k, _TRANS_B,
-                                preferred_element_type=jnp.float32)
-        s = jnp.where(valid, s, _NEG_INF)
-        _online_update(s, v_ref[0, :, 0, :], m_s, l_s, acc_s)
+        _attend_heads(q_ref, k_ref, v_ref, valid, m_s, l_s, acc_s,
+                      ks_ref, vs_ref)
 
     @pl.when(j == nl - 1)
-    def _finalize():
-        o_ref[0, 0] = acc_s[...] / jnp.maximum(l_s[...], 1e-30)
+    def _fin():
+        _finalize(o_ref, l_s, acc_s)
 
 
-def decode(qf, k_cache, v_cache, kpos, qpos, *, window, block, interpret):
-    """qf: (B, KH, G, D) pre-scaled; caches (B, L, KH, D/Dv); kpos (B, L);
-    qpos (B, 1) int32.  Returns (B, KH, G, Dv) fp32."""
+def _decode_call(qf, k_cache, v_cache, scales, kpos, qpos, *, window,
+                 block, interpret):
     b, kh, g, d = qf.shape
     length = k_cache.shape[1]
     dv = v_cache.shape[-1]
     nl = length // block
-    kernel = functools.partial(_decode_kernel, window=window, nl=nl)
-    cache_map = lambda b_, kh_, j: (b_, j, kh_, 0)
+    cache_map = lambda b_, j, qp: (b_, j, 0, 0)
+    in_specs = [
+        pl.BlockSpec((1, 1, block), lambda b_, j, qp: (b_, 0, j)),
+        pl.BlockSpec((1, kh, g, d), lambda b_, j, qp: (b_, 0, 0, 0)),
+        pl.BlockSpec((1, block, kh, d), cache_map),
+        pl.BlockSpec((1, block, kh, dv), cache_map),
+    ]
+    in_specs += [pl.BlockSpec((1, kh, block), lambda b_, j, qp: (b_, 0, j))
+                 ] * len(scales)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, nl),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, kh, g, dv),
+                               lambda b_, j, qp: (b_, 0, 0, 0)),
+        scratch_shapes=_scratch(kh, g, dv),
+    )
+    kernel = functools.partial(_decode_kernel, window=window, nl=nl,
+                               quantized=bool(scales))
     return pl.pallas_call(
-        kernel,
-        grid=(b, kh, nl),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda b_, kh_, j: (b_, 0)),
-            pl.BlockSpec((1, block), lambda b_, kh_, j: (b_, j)),
-            pl.BlockSpec((1, 1, g, d), lambda b_, kh_, j: (b_, kh_, 0, 0)),
-            pl.BlockSpec((1, block, 1, d), cache_map),
-            pl.BlockSpec((1, block, 1, dv), cache_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, dv),
-                               lambda b_, kh_, j: (b_, kh_, 0, 0)),
+        kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kh, g, dv), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, dv), jnp.float32),
-        ],
         interpret=interpret,
-    )(qpos, kpos, qf, k_cache, v_cache)
+    )(qpos, kpos.reshape(b, 1, length), qf, k_cache, v_cache, *scales)
 
 
-def _decode_q8_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, ks_ref,
-                      vs_ref, o_ref, m_s, l_s, acc_s, *, window, nl):
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_s[...] = jnp.full_like(m_s, _NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
-
-    qp = qpos_ref[...]
-    kp = kpos_ref[...]
-    valid = _valid(kp, qp, window)
-
-    @pl.when(jnp.any(valid))
-    def _compute():
-        q = q_ref[0, 0]                        # (G, D)
-        k = k_ref[0, :, 0, :].astype(q.dtype)  # (bL, D) int8 codes
-        s = jax.lax.dot_general(q, k, _TRANS_B,
-                                preferred_element_type=jnp.float32)
-        s = s * ks_ref[0]                      # fold K absmax scales
-        s = jnp.where(valid, s, _NEG_INF)
-        _online_update(s, v_ref[0, :, 0, :].astype(q.dtype), m_s, l_s,
-                       acc_s, p_scale=vs_ref[0])  # fold V absmax scales
-
-    @pl.when(j == nl - 1)
-    def _finalize():
-        o_ref[0, 0] = acc_s[...] / jnp.maximum(l_s[...], 1e-30)
+def decode(qf, k_cache, v_cache, kpos, qpos, *, window, block, interpret):
+    """qf: (B, KH, G, D) pre-scaled; caches (B, L, KH, D/Dv); kpos (B, L);
+    qpos (B,) int32.  Returns (B, KH, G, Dv) fp32."""
+    return _decode_call(qf, k_cache, v_cache, (), kpos, qpos, window=window,
+                        block=block, interpret=interpret)
 
 
 def decode_q8(qf, k_codes, v_codes, k_scale, v_scale, kpos, qpos, *,
@@ -180,34 +211,9 @@ def decode_q8(qf, k_codes, v_codes, k_scale, v_scale, kpos, qpos, *,
     (B, L, KH, D) int8; scales (B, KH, L) fp32 (pre-transposed by the
     caller — they are D-times smaller than the codes).  Returns
     (B, KH, G, D) fp32."""
-    b, kh, g, d = qf.shape
-    length = k_codes.shape[1]
-    nl = length // block
-    kernel = functools.partial(_decode_q8_kernel, window=window, nl=nl)
-    cache_map = lambda b_, kh_, j: (b_, j, kh_, 0)
-    scale_map = lambda b_, kh_, j: (b_, kh_, j)
-    return pl.pallas_call(
-        kernel,
-        grid=(b, kh, nl),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda b_, kh_, j: (b_, 0)),
-            pl.BlockSpec((1, block), lambda b_, kh_, j: (b_, j)),
-            pl.BlockSpec((1, 1, g, d), lambda b_, kh_, j: (b_, kh_, 0, 0)),
-            pl.BlockSpec((1, block, 1, d), cache_map),
-            pl.BlockSpec((1, block, 1, d), cache_map),
-            pl.BlockSpec((1, 1, block), scale_map),
-            pl.BlockSpec((1, 1, block), scale_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda b_, kh_, j: (b_, kh_, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, kh, g, d), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, d), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qpos, kpos, qf, k_codes, v_codes, k_scale, v_scale)
+    return _decode_call(qf, k_codes, v_codes, (k_scale, v_scale), kpos,
+                        qpos, window=window, block=block,
+                        interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -216,116 +222,90 @@ def decode_q8(qf, k_codes, v_codes, k_scale, v_scale, kpos, qpos, *,
 #
 # The continuous-batching engine stores the KV cache as fixed-size pages
 # in a shared pool; each slot owns a page table mapping logical page j to
-# a physical pool page.  The page table rides in as a *scalar-prefetch*
-# operand (PrefetchScalarGridSpec), so the BlockSpec index maps read it to
-# DMA each slot's pages straight out of the pool — no gathered contiguous
-# copy of the cache ever exists.  Unallocated entries (-1) are clamped to
-# physical page 0 (the engine's reserved null page) for the DMA and masked
-# out in-kernel via the prefetched table, so whatever page 0 holds never
-# contributes.  Grid: (S, KH, npp), page axis innermost — the same
-# online-softmax scratch sweep as the contiguous kernels above.
+# a physical pool page.  The page table and the slots' query positions
+# ride in as *scalar-prefetch* operands (PrefetchScalarGridSpec), so the
+# BlockSpec index maps read the table to DMA each slot's pages straight
+# out of the pool — no gathered contiguous copy of the cache ever exists.
+# Unallocated entries (-1) are clamped to physical page 0 (the engine's
+# reserved null page) for the DMA and masked out in-kernel via the
+# prefetched table, so whatever page 0 holds never contributes.  Grid:
+# (S, npp), page axis innermost; each step takes one (pg, KH, D) page
+# with every KV head, the same online-softmax sweep as the ring kernels.
+# A page block spans whole trailing axes, so every page size of two or
+# more compiles (:func:`paged_compiles`).
 
 def _pt_phys(pt_ref, s, j):
     """Clamped physical page for (slot s, logical page j)."""
     return jnp.maximum(pt_ref[s, j], 0)
 
 
-def _decode_paged_kernel(pt_ref, qpos_ref, q_ref, k_ref, v_ref, pos_ref,
-                         o_ref, m_s, l_s, acc_s, *, window, npp):
+def _paged_kernel(pt_ref, qpos_ref, q_ref, k_ref, v_ref, pos_ref, *rest,
+                  window, npp, quantized):
+    if quantized:
+        ks_ref, vs_ref, o_ref, m_s, l_s, acc_s = rest
+    else:
+        ks_ref = vs_ref = None
+        o_ref, m_s, l_s, acc_s = rest
     s_idx = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        m_s[...] = jnp.full_like(m_s, _NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
+        _init_state(m_s, l_s, acc_s)
 
-    qp = qpos_ref[...]  # (1, 1) int32
-    kp = pos_ref[...]   # (1, pg) int32
-    valid = _valid(kp, qp, window) & (pt_ref[s_idx, j] >= 0)
+    valid = _valid(pos_ref[0], qpos_ref[s_idx], window) \
+        & (pt_ref[s_idx, j] >= 0)                  # (1, pg)
 
-    @pl.when(jnp.any(valid))
+    @pl.when(_any(valid))
     def _compute():
-        q = q_ref[0, 0]          # (G, D), pre-scaled
-        k = k_ref[0, :, 0, :]    # (pg, D)
-        s = jax.lax.dot_general(q, k, _TRANS_B,
-                                preferred_element_type=jnp.float32)
-        s = jnp.where(valid, s, _NEG_INF)
-        _online_update(s, v_ref[0, :, 0, :], m_s, l_s, acc_s)
+        _attend_heads(q_ref, k_ref, v_ref, valid, m_s, l_s, acc_s,
+                      ks_ref, vs_ref)
 
     @pl.when(j == npp - 1)
-    def _finalize():
-        o_ref[0, 0] = acc_s[...] / jnp.maximum(l_s[...], 1e-30)
+    def _fin():
+        _finalize(o_ref, l_s, acc_s)
+
+
+def _paged_call(qf, k_pool, v_pool, scales, pos_pool, page_table, qpos, *,
+                window, interpret):
+    s, kh, g, d = qf.shape
+    n_pages, pg = k_pool.shape[:2]
+    dv = v_pool.shape[-1]
+    npp = page_table.shape[1]
+    page_map = lambda s_, j, pt, qp: (_pt_phys(pt, s_, j), 0, 0, 0)
+    row_map = lambda s_, j, pt, qp: (_pt_phys(pt, s_, j), 0, 0)
+    in_specs = [
+        pl.BlockSpec((1, kh, g, d), lambda s_, j, pt, qp: (s_, 0, 0, 0)),
+        pl.BlockSpec((1, pg, kh, d), page_map),
+        pl.BlockSpec((1, pg, kh, dv), page_map),
+        pl.BlockSpec((1, 1, pg), row_map),
+    ]
+    in_specs += [pl.BlockSpec((1, kh, pg), row_map)] * len(scales)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(s, npp),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, kh, g, dv),
+                               lambda s_, j, pt, qp: (s_, 0, 0, 0)),
+        scratch_shapes=_scratch(kh, g, dv),
+    )
+    kernel = functools.partial(_paged_kernel, window=window, npp=npp,
+                               quantized=bool(scales))
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((s, kh, g, dv), jnp.float32),
+        interpret=interpret,
+    )(page_table, qpos, qf, k_pool, v_pool,
+      pos_pool.reshape(n_pages, 1, pg), *scales)
 
 
 def decode_paged(qf, k_pool, v_pool, pos_pool, page_table, qpos, *,
                  window, interpret):
     """Paged-pool decode.  qf: (S, KH, G, D) pre-scaled; pools
     (P, pg, KH, D/Dv); pos_pool (P, pg) int32; page_table (S, npp) int32
-    (-1 = unallocated); qpos (S, 1) int32.  Returns (S, KH, G, Dv) fp32."""
-    s, kh, g, d = qf.shape
-    pg = k_pool.shape[1]
-    dv = v_pool.shape[-1]
-    npp = page_table.shape[1]
-    kernel = functools.partial(_decode_paged_kernel, window=window, npp=npp)
-    pool_map = lambda s_, kh_, j, pt: (_pt_phys(pt, s_, j), 0, kh_, 0)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(s, kh, npp),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda s_, kh_, j, pt: (s_, 0)),
-            pl.BlockSpec((1, 1, g, d), lambda s_, kh_, j, pt: (s_, kh_, 0, 0)),
-            pl.BlockSpec((1, pg, 1, d), pool_map),
-            pl.BlockSpec((1, pg, 1, dv), pool_map),
-            pl.BlockSpec((1, pg),
-                         lambda s_, kh_, j, pt: (_pt_phys(pt, s_, j), 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, dv),
-                               lambda s_, kh_, j, pt: (s_, kh_, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, dv), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, kh, g, dv), jnp.float32),
-        interpret=interpret,
-    )(page_table, qpos, qf, k_pool, v_pool, pos_pool)
-
-
-def _decode_paged_q8_kernel(pt_ref, qpos_ref, q_ref, k_ref, v_ref, ks_ref,
-                            vs_ref, pos_ref, o_ref, m_s, l_s, acc_s, *,
-                            window, npp):
-    s_idx = pl.program_id(0)
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_s[...] = jnp.full_like(m_s, _NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
-
-    qp = qpos_ref[...]
-    kp = pos_ref[...]
-    valid = _valid(kp, qp, window) & (pt_ref[s_idx, j] >= 0)
-
-    @pl.when(jnp.any(valid))
-    def _compute():
-        q = q_ref[0, 0]                        # (G, D)
-        k = k_ref[0, :, 0, :].astype(q.dtype)  # (pg, D) int8 codes
-        s = jax.lax.dot_general(q, k, _TRANS_B,
-                                preferred_element_type=jnp.float32)
-        s = s * ks_ref[0]                      # fold K absmax scales
-        s = jnp.where(valid, s, _NEG_INF)
-        _online_update(s, v_ref[0, :, 0, :].astype(q.dtype), m_s, l_s,
-                       acc_s, p_scale=vs_ref[0])  # fold V absmax scales
-
-    @pl.when(j == npp - 1)
-    def _finalize():
-        o_ref[0, 0] = acc_s[...] / jnp.maximum(l_s[...], 1e-30)
+    (-1 = unallocated); qpos (S,) int32.  Returns (S, KH, G, Dv) fp32."""
+    return _paged_call(qf, k_pool, v_pool, (), pos_pool, page_table, qpos,
+                       window=window, interpret=interpret)
 
 
 def decode_paged_q8(qf, k_pool, v_pool, k_scale, v_scale, pos_pool,
@@ -333,36 +313,5 @@ def decode_paged_q8(qf, k_pool, v_pool, k_scale, v_scale, pos_pool,
     """Paged int8-pool decode.  Codes (P, pg, KH, D) int8; scales
     (P, KH, pg) fp32 (pre-transposed by the caller); otherwise as
     :func:`decode_paged`.  Returns (S, KH, G, D) fp32."""
-    s, kh, g, d = qf.shape
-    pg = k_pool.shape[1]
-    npp = page_table.shape[1]
-    kernel = functools.partial(_decode_paged_q8_kernel, window=window,
-                               npp=npp)
-    pool_map = lambda s_, kh_, j, pt: (_pt_phys(pt, s_, j), 0, kh_, 0)
-    scale_map = lambda s_, kh_, j, pt: (_pt_phys(pt, s_, j), kh_, 0)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(s, kh, npp),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda s_, kh_, j, pt: (s_, 0)),
-            pl.BlockSpec((1, 1, g, d), lambda s_, kh_, j, pt: (s_, kh_, 0, 0)),
-            pl.BlockSpec((1, pg, 1, d), pool_map),
-            pl.BlockSpec((1, pg, 1, d), pool_map),
-            pl.BlockSpec((1, 1, pg), scale_map),
-            pl.BlockSpec((1, 1, pg), scale_map),
-            pl.BlockSpec((1, pg),
-                         lambda s_, kh_, j, pt: (_pt_phys(pt, s_, j), 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda s_, kh_, j, pt: (s_, kh_, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, d), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, kh, g, d), jnp.float32),
-        interpret=interpret,
-    )(page_table, qpos, qf, k_pool, v_pool, k_scale, v_scale, pos_pool)
+    return _paged_call(qf, k_pool, v_pool, (k_scale, v_scale), pos_pool,
+                       page_table, qpos, window=window, interpret=interpret)

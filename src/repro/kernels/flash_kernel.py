@@ -46,6 +46,14 @@ _TRANS_A = (((0,), (0,)), ((), ()))   # (a, b) -> a.T @ b
 _PLAIN = (((1,), (0,)), ((), ()))     # (a, b) -> a @ b
 
 
+def compiles(block: int, sq: int, skv: int) -> bool:
+    """Whether the compiled TPU kernels take ``block`` over padded query
+    / key lengths ``sq`` / ``skv``: the (1, block) key-position tiles put
+    the block on the lane axis, so it must be a multiple of 128 unless
+    one block spans both sequences."""
+    return block % 128 == 0 or sq == block == skv
+
+
 def _visible(qp, kp, window):
     """Block-level skip predicate from runtime position extrema."""
     vis = jnp.min(kp) <= jnp.max(qp)

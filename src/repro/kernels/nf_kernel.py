@@ -2,13 +2,15 @@
 
 One grid step processes a (BLOCKS_PER_TILE x G) tile of activation blocks:
 per-block (min, range) reduction, normalize onto [-1, 1], nearest-neighbor
-lookup against the <=16-entry NF codebook held in VMEM (broadcast compare
-over a tiny trailing axis — VPU-friendly, no gather), then shift-or pack
-to uint8 words.  Outputs per tile: packed codes + per-block fp16 (min,
-range) side-info (the "auxiliary information" whose wire cost the paper
-discusses for QLoRA).
+lookup against the <=16-entry NF codebook held in SMEM (an unrolled
+compare over the levels — VPU-friendly, no gather, first level wins ties
+exactly like ``argmin``), then int32 shift-or pack along sublanes
+(``lane_pack``) and one uint8 store.  Outputs per tile: packed codes +
+per-block (min, range) side-info (the "auxiliary information" whose wire
+cost the paper discusses for QLoRA), emitted as fp32 because Mosaic has
+no (rows, 1) fp16 tile; the caller narrows them to the fp16 wire form.
 
-VMEM: 128 x 64 fp32 tile (32 KiB) + codebook (64 B) + outputs — tiny; the
+VMEM: 128 x 64 fp32 tile (32 KiB) + packing scratch + outputs — tiny; the
 kernel is bandwidth-bound by design (quantization is a streaming op).
 Double quantization of the ranges happens outside the kernel (it touches
 only NB/G scalars, 1/64th of the data).
@@ -22,48 +24,43 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.packing import storage_bits
+from repro.kernels.lane_pack import pack_rows, unpack_rows
 
 BLOCKS_PER_TILE = 128
 _EPS = 1e-8
 
 
-def _quant_kernel(x_ref, book_ref, codes_ref, m_ref, r_ref, *, bits: int,
-                  g: int):
+def _quant_kernel(x_ref, book_ref, codes_ref, m_ref, r_ref, scr, *,
+                  bits: int):
     x = x_ref[...].astype(jnp.float32)  # (BT, G)
     m = x.min(axis=1, keepdims=True)
     mx = x.max(axis=1, keepdims=True)
     rng = mx - m
     norm = 2.0 * (x - m) / (rng + _EPS) - 1.0
-    book = book_ref[...].astype(jnp.float32)  # (1, n_levels)
-    dist = jnp.abs(norm[..., None] - book[0][None, None, :])
-    codes = jnp.argmin(dist, axis=-1).astype(jnp.uint8)  # (BT, G)
-    sb = storage_bits(bits)
-    per = 8 // sb
-    grouped = codes.reshape(BLOCKS_PER_TILE, g // per, per)
-    shifts = (jnp.arange(per, dtype=jnp.uint8) * sb)[None, None, :]
-    codes_ref[...] = (grouped << shifts).sum(axis=-1).astype(jnp.uint8)
-    m_ref[...] = m.astype(jnp.float16)
-    r_ref[...] = rng.astype(jnp.float16)
+    best = jnp.abs(norm - book_ref[0])
+    codes = jnp.zeros(norm.shape, jnp.int32)
+    for i in range(1, book_ref.shape[0]):
+        dist = jnp.abs(norm - book_ref[i])
+        closer = dist < best
+        codes = jnp.where(closer, i, codes)
+        best = jnp.where(closer, dist, best)
+    codes_ref[...] = pack_rows(codes, bits, scr).astype(jnp.uint8)
+    m_ref[...] = m
+    r_ref[...] = rng
 
 
-def _dequant_kernel(w_ref, m_ref, r_ref, book_ref, out_ref, *, bits: int,
-                    g: int):
-    words = w_ref[...]
-    m = m_ref[...].astype(jnp.float32)
-    rng = r_ref[...].astype(jnp.float32)
-    book = book_ref[...].astype(jnp.float32)[0]  # (n_levels,)
-    sb = storage_bits(bits)
-    per = 8 // sb
-    shifts = (jnp.arange(per, dtype=jnp.uint8) * sb)[None, None, :]
-    mask = jnp.uint8((1 << sb) - 1)
-    codes = ((words[..., None] >> shifts) & mask).reshape(
-        BLOCKS_PER_TILE, g)
-    # gather-free lookup: one-hot contraction over the tiny codebook axis
-    onehot = (codes[..., None] ==
-              jnp.arange(book.shape[0], dtype=jnp.uint8)).astype(jnp.float32)
-    norm = (onehot * book[None, None, :]).sum(-1)
+def _dequant_kernel(w_ref, m_ref, r_ref, book_ref, out_ref, scr, *,
+                    bits: int):
+    m = m_ref[...]
+    rng = r_ref[...]
+    codes = unpack_rows(w_ref[...].astype(jnp.int32), bits, scr)  # (BT, G)
+    # gather-free lookup: one select per codebook level
+    norm = jnp.zeros(codes.shape, jnp.float32)
+    for i in range(book_ref.shape[0]):
+        norm = jnp.where(codes == i, book_ref[i], norm)
     out_ref[...] = ((norm + 1.0) / 2.0 * rng + m).astype(out_ref.dtype)
 
 
@@ -73,13 +70,12 @@ def quantize_pallas(blocks: jnp.ndarray, book: jnp.ndarray, bits: int, *,
     nb, g = blocks.shape
     per = 8 // storage_bits(bits)
     grid = (nb // BLOCKS_PER_TILE,)
-    book2d = book.reshape(1, -1)
     return pl.pallas_call(
-        functools.partial(_quant_kernel, bits=bits, g=g),
+        functools.partial(_quant_kernel, bits=bits),
         grid=grid,
         in_specs=[
             pl.BlockSpec((BLOCKS_PER_TILE, g), lambda i: (i, 0)),
-            pl.BlockSpec((1, book2d.shape[1]), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((BLOCKS_PER_TILE, g // per), lambda i: (i, 0)),
@@ -88,11 +84,12 @@ def quantize_pallas(blocks: jnp.ndarray, book: jnp.ndarray, bits: int, *,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((nb, g // per), jnp.uint8),
-            jax.ShapeDtypeStruct((nb, 1), jnp.float16),
-            jax.ShapeDtypeStruct((nb, 1), jnp.float16),
+            jax.ShapeDtypeStruct((nb, 1), jnp.float32),
+            jax.ShapeDtypeStruct((nb, 1), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM((g, BLOCKS_PER_TILE), jnp.int32)],
         interpret=interpret,
-    )(blocks, book2d)
+    )(blocks, book.astype(jnp.float32))
 
 
 def dequantize_pallas(words: jnp.ndarray, m: jnp.ndarray, rng: jnp.ndarray,
@@ -101,17 +98,18 @@ def dequantize_pallas(words: jnp.ndarray, m: jnp.ndarray, rng: jnp.ndarray,
     nb = words.shape[0]
     per = 8 // storage_bits(bits)
     grid = (nb // BLOCKS_PER_TILE,)
-    book2d = book.reshape(1, -1)
     return pl.pallas_call(
-        functools.partial(_dequant_kernel, bits=bits, g=g),
+        functools.partial(_dequant_kernel, bits=bits),
         grid=grid,
         in_specs=[
             pl.BlockSpec((BLOCKS_PER_TILE, g // per), lambda i: (i, 0)),
             pl.BlockSpec((BLOCKS_PER_TILE, 1), lambda i: (i, 0)),
             pl.BlockSpec((BLOCKS_PER_TILE, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, book2d.shape[1]), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((BLOCKS_PER_TILE, g), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, g), out_dtype),
+        scratch_shapes=[pltpu.VMEM((g, BLOCKS_PER_TILE), jnp.int32)],
         interpret=interpret,
-    )(words, m, rng, book2d)
+    )(words, m.astype(jnp.float32), rng.astype(jnp.float32),
+      book.astype(jnp.float32))

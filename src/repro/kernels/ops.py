@@ -109,7 +109,8 @@ def nf_quantize(x: jnp.ndarray, bits: int, block: int = 64,
     book = jnp.asarray(nf_codebook(bits), jnp.float32)
     words, m, rng = nf_kernel.quantize_pallas(blocks, book, bits,
                                               interpret=_interpret())
-    words, m, rng = words[:nb], m[:nb], rng[:nb]
+    words = words[:nb]
+    m, rng = m[:nb].astype(jnp.float16), rng[:nb].astype(jnp.float16)
     aux = dict(block_min=m)
     if double_quant:
         codes, gscale = _double_quant(rng.astype(jnp.float32), dq_group)

@@ -11,10 +11,12 @@ x * bits/16 — the naive jnp path materializes the intermediate codes at
 
 TPU notes: COLS=1024 keeps the lane dim a multiple of 128 both before
 (1024) and after packing (1024 * bits / 8 >= 128 for bits >= 1); the
-(ROWS x COLS) fp32 tile + packed output is ~36 KiB, far under the ~16 MiB
-VMEM budget, leaving room for double buffering.  The MXU is not involved —
-this is a VPU kernel; the per-(row)-scalar (lo, hi) side inputs ride along
-as a (ROWS, 2) VMEM tile.
+(ROWS x COLS) fp32 tile + packed output + the (COLS x ROWS) int32 packing
+scratch stay far under the ~16 MiB VMEM budget, leaving room for double
+buffering.  Codes are int32 until the final uint8 store (Mosaic has no
+float <-> uint8 cast) and are packed along sublanes (``lane_pack``).  The
+MXU is not involved — this is a VPU kernel; the per-(row)-scalar
+(lo, hi) side inputs ride along as a (ROWS, 2) VMEM tile.
 
 Validated on CPU with interpret=True against kernels/ref.py.
 """
@@ -25,15 +27,17 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.packing import storage_bits
+from repro.kernels.lane_pack import pack_rows, unpack_rows
 
 ROWS = 8
 COLS = 1024
 _EPS = 1e-6
 
 
-def _quantize_kernel(x_ref, stats_ref, out_ref, *, bits: int):
+def _quantize_kernel(x_ref, stats_ref, out_ref, scr, *, bits: int):
     x = x_ref[...].astype(jnp.float32)  # (ROWS, COLS)
     lo = stats_ref[:, 0:1]
     hi = stats_ref[:, 1:2]
@@ -46,27 +50,17 @@ def _quantize_kernel(x_ref, stats_ref, out_ref, *, bits: int):
     else:
         z = jnp.round(half * e - 0.5) + 0.5
     z = jnp.clip(z, -half, half)
-    idx = (z + half).astype(jnp.uint8)
-    # shift-or pack: per = codes per uint8 word
-    sb = storage_bits(bits)
-    per = 8 // sb
-    grouped = idx.reshape(ROWS, COLS // per, per)
-    shifts = (jnp.arange(per, dtype=jnp.uint8) * sb)[None, None, :]
-    words = (grouped << shifts).sum(axis=-1).astype(jnp.uint8)
-    out_ref[...] = words
+    idx = (z + half).astype(jnp.int32)
+    out_ref[...] = pack_rows(idx, bits, scr).astype(jnp.uint8)
 
 
-def _dequantize_kernel(w_ref, stats_ref, out_ref, *, bits: int):
-    words = w_ref[...]  # (ROWS, COLS//per) uint8
+def _dequantize_kernel(w_ref, stats_ref, out_ref, scr, *, bits: int):
+    words = w_ref[...].astype(jnp.int32)  # (ROWS, COLS//per)
     lo = stats_ref[:, 0:1]
     hi = stats_ref[:, 1:2]
     d = 2 ** bits
     half = (d - 1) / 2.0
-    sb = storage_bits(bits)
-    per = 8 // sb
-    shifts = (jnp.arange(per, dtype=jnp.uint8) * sb)[None, None, :]
-    mask = jnp.uint8((1 << sb) - 1)
-    codes = ((words[..., None] >> shifts) & mask).reshape(ROWS, COLS)
+    codes = unpack_rows(words, bits, scr)  # (ROWS, COLS)
     c = (codes.astype(jnp.float32) - half) / half
     out_ref[...] = ((c + 1.0) / 2.0 * (hi - lo) + lo).astype(out_ref.dtype)
 
@@ -86,6 +80,7 @@ def quantize_pallas(x2d: jnp.ndarray, stats: jnp.ndarray, bits: int, *,
         ],
         out_specs=pl.BlockSpec((ROWS, COLS // per), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((r, c // per), jnp.uint8),
+        scratch_shapes=[pltpu.VMEM((COLS, ROWS), jnp.int32)],
         interpret=interpret,
     )(x2d, stats)
 
@@ -105,5 +100,6 @@ def dequantize_pallas(words: jnp.ndarray, stats: jnp.ndarray, bits: int, *,
         ],
         out_specs=pl.BlockSpec((ROWS, COLS), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((r, c), out_dtype),
+        scratch_shapes=[pltpu.VMEM((COLS, ROWS), jnp.int32)],
         interpret=interpret,
     )(words, stats)

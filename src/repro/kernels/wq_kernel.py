@@ -6,22 +6,25 @@ weights stored as ``core.packing`` bitstreams (0.5 B/element at int4
 instead of 2 B bf16), the matmul must unpack + dequantize on the fly —
 done here inside the MXU pipeline so the codes never exist at 8 bits in
 HBM: each grid step reads a ``(bk * bits / 8, bn)`` uint8 tile and the
-``(bk / group, bn)`` fp16 scale/min tiles into VMEM, rebuilds the codes
-with uint32 word arithmetic (8 consecutive codes of a column span
-exactly ``bits`` whole bytes, so a ``(nb, bits, bn)`` reshape + byte
-shifts yields one 32-bit word per code octet — ``bits <= 4`` fits), maps
-``code * scale + min``, and contracts the dequantized ``(bk, bn)`` tile
-against the activation tile in the activation dtype with an fp32 VMEM
-accumulator.
+``(bk / group, bn)`` scale/min tiles into VMEM, rebuilds the codes with
+int32 word arithmetic (8 consecutive codes of a column span exactly
+``bits`` whole bytes, so a ``(nb, bits, bn)`` reshape + byte shifts
+yields one 32-bit word per code octet — ``bits <= 4`` fits; int32 because
+Mosaic reduces no unsigned type), maps ``code * scale + min``, and
+contracts the dequantized ``(bk, bn)`` tile against the activation tile
+in the activation dtype with an fp32 VMEM accumulator.  The scales and
+mins are stored fp16 and widened to fp32 by the wrapper: the v5e vector
+unit loads no fp16.
 
 HBM traffic per output tile: ``bits/16`` of the bf16 weight bytes plus
 the fp16 side info (``2 * 16 / (group * bits)`` relative) — the ~3.76x
 serve-time weight-bandwidth cut measured by ``benchmarks/wq_bench.py``.
 
 Grid: ``(M / bm, N / bn, K / bk)`` with K innermost; the wrapper pads M
-to ``bm``, N to ``bn = 128`` (lane width) and K to ``bk`` (a multiple of
-``group`` and >= 128) — padded K rows decode against zero-padded
-activations, so they contribute exactly 0.  Validated on CPU with
+to ``bm``, N to ``bn = 128`` (lane width) and K to ``bk`` — the whole K
+axis up to ``MAX_BK``, else 8 groups, so the scale tiles' sublane count
+is legal — and padded K rows decode against zero-padded activations, so
+they contribute exactly 0.  Validated on CPU with
 ``interpret=True`` against ``kernels/ref.py::wq_matmul_ref``.
 """
 from __future__ import annotations
@@ -37,6 +40,7 @@ from repro.core.packing import packed_size
 
 BM = 16   # sublane multiple for both fp32 (8) and bf16 (16) tiles
 BN = 128  # lane width
+MAX_BK = 4096  # longest K axis taken as one block
 
 
 def _matmul_kernel(x_ref, w_ref, s_ref, m_ref, o_ref, acc_ref, *,
@@ -47,20 +51,22 @@ def _matmul_kernel(x_ref, w_ref, s_ref, m_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    words = w_ref[...]                       # (bk * bits // 8, bn) uint8
+    words = w_ref[...].astype(jnp.int32)     # (bk * bits // 8, bn) bytes
     nb = words.shape[0] // bits              # 8-code octets in this K tile
     bn = words.shape[1]
-    w32 = words.reshape(nb, bits, bn).astype(jnp.uint32)
-    byte_shifts = (jnp.arange(bits, dtype=jnp.uint32) * 8)[None, :, None]
+    w32 = words.reshape(nb, bits, bn)
+    byte_shifts = (jnp.arange(bits, dtype=jnp.int32) * 8)[None, :, None]
+    # the shifted bytes are disjoint, so the (wrapping) int32 sum is their
+    # bitwise OR: one 32-bit word per code octet
     word32 = (w32 << byte_shifts).sum(axis=1)          # (nb, bn)
-    code_shifts = (jnp.arange(8, dtype=jnp.uint32) * bits)[None, :, None]
-    mask = jnp.uint32(2 ** bits - 1)
-    codes = (word32[:, None, :] >> code_shifts) & mask  # (nb, 8, bn)
+    code_shifts = (jnp.arange(8, dtype=jnp.int32) * bits)[None, :, None]
+    codes = jax.lax.shift_right_logical(word32[:, None, :], code_shifts) \
+        & (2 ** bits - 1)                               # (nb, 8, bn)
     codes = codes.reshape(nb * 8, bn).astype(jnp.float32)
 
     gpb = (nb * 8) // group                  # groups per K tile (>= 1)
-    scale = s_ref[...].astype(jnp.float32)[:, None, :]  # (gpb, 1, bn)
-    mn = m_ref[...].astype(jnp.float32)[:, None, :]
+    scale = s_ref[...][:, None, :]           # (gpb, 1, bn) fp32
+    mn = m_ref[...][:, None, :]
     w = (codes.reshape(gpb, group, bn) * scale + mn).reshape(nb * 8, bn)
 
     x = x_ref[...]                           # (bm, bk) activation dtype
@@ -102,7 +108,10 @@ def matmul_pallas(x2d: jnp.ndarray, words: jnp.ndarray,
     n_groups = -(-d_in // group)
     assert scales.shape == (n_groups, d_out), scales.shape
 
-    bk = group * max(1, -(-BN // group))     # multiple of group, >= 128
+    # the (gpb, BN) scale/min tiles put gpb on sublanes: it must be a
+    # multiple of 8 or every group, so short K axes take one block
+    gpb = n_groups if n_groups * group <= MAX_BK else 8
+    bk = group * gpb
     m_pad = -(-m // BM) * BM
     n_pad = -(-d_out // BN) * BN
     k_pad = -(-d_in // bk) * bk
@@ -110,10 +119,12 @@ def matmul_pallas(x2d: jnp.ndarray, words: jnp.ndarray,
 
     x_p = _pad_to(_pad_to(x2d, 1, k_pad), 0, m_pad)
     w_p = _pad_to(_pad_to(words, 0, k_pad * bits // 8), 1, n_pad)
-    s_p = _pad_to(_pad_to(scales, 0, k_pad // group), 1, n_pad)
-    mn_p = _pad_to(_pad_to(mins, 0, k_pad // group), 1, n_pad)
+    # fp32 side info: the v5e vector unit has no fp16 loads
+    s_p = _pad_to(_pad_to(scales.astype(jnp.float32), 0, k_pad // group),
+                  1, n_pad)
+    mn_p = _pad_to(_pad_to(mins.astype(jnp.float32), 0, k_pad // group),
+                   1, n_pad)
 
-    gpb = bk // group
     out = pl.pallas_call(
         functools.partial(_matmul_kernel, bits=bits, group=group, n_k=n_k),
         grid=(m_pad // BM, n_pad // BN, n_k),

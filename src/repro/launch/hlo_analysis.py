@@ -64,6 +64,23 @@ def _shape_elems_bytes(text: str) -> Tuple[int, int]:
     return elems, total
 
 
+def _collective_bytes(rhs: str) -> int:
+    """Bytes a collective instruction moves: its result shape.  An async
+    ``*-start`` on TPU returns an (operand, result, context...) tuple;
+    its traffic is the result element."""
+    if not rhs.startswith("("):
+        return _shape_elems_bytes(rhs.split("(")[0])[1]
+    depth = 0
+    for end, ch in enumerate(rhs):
+        depth += (ch == "(") - (ch == ")")
+        if depth == 0:
+            break
+    shapes = _SHAPE_RE.findall(rhs[:end + 1])
+    if len(shapes) >= 2:
+        shapes = shapes[1:2]
+    return sum(_shape_elems_bytes(f"{dt}[{dims}]")[1] for dt, dims in shapes)
+
+
 def split_computations(hlo: str) -> Tuple[Dict[str, List[str]], str]:
     """computation name -> instruction lines; plus the ENTRY name.
 
@@ -236,7 +253,7 @@ def collective_permute_pairs(hlo: str) -> Dict[Tuple[int, int], int]:
             rhs = m.group(2)
             if not re.search(r"\bcollective-permute(?:-start)?\(", rhs):
                 continue
-            _, out_b = _shape_elems_bytes(rhs.split("(")[0])
+            out_b = _collective_bytes(rhs)
             pm = _CP_PAIRS_RE.search(rhs)
             if not pm:
                 continue
@@ -299,7 +316,7 @@ def analyze(hlo: str) -> Dict:
                 flops += _dot_flops(stripped, shape_map)
             for op in COLLECTIVE_OPS:
                 if re.search(rf"\b{op}(?:-start)?\(", rhs):
-                    coll[op] += out_b
+                    coll[op] += _collective_bytes(rhs)
                     coll_counts[op] += 1
                     break
         per_comp[name] = (flops, bytes_out, dict(coll), dict(coll_counts))

@@ -57,7 +57,6 @@ from typing import Dict, Iterable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
@@ -273,10 +272,10 @@ def build_gpipe_step(cfg: ArchConfig, mesh, split: SplitConfig,
     param_specs = stage_param_specs(cfg, n_stages, lora_rank=lora_rank)
     tok_spec = P(None, "data", None)  # (n_micro, B, S)
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(param_specs, tok_spec, tok_spec),
              out_specs=(P(), P()),
-             check_rep=False)
+             check_vma=False)
     def step(params, tokens, labels):
         stage = jax.lax.axis_index("pod")
         my_blocks = jax.tree_util.tree_map(lambda a: a[0],
@@ -408,10 +407,10 @@ def build_hub_step(cfg: ArchConfig, mesh, hub: HubConfig, n_micro: int,
                                     lora_rank=lora_rank)
     tok_spec = P(None, None, "data", None)  # (n_micro, N, B, S)
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(param_specs, tok_spec, tok_spec),
              out_specs=(P(), P(), P()),
-             check_rep=False)
+             check_vma=False)
     def step(params, tokens, labels):
         pod = jax.lax.axis_index("pod")
         is_server = pod == n_clients
@@ -525,8 +524,8 @@ def build_hub_grad_step(cfg: ArchConfig, mesh, hub: HubConfig,
     ad_specs = stage_param_specs(cfg, n_clients + 1, cfg.n_layers // 2,
                                  lora_rank=lora_rank)["adapters"]
 
-    @partial(shard_map, mesh=mesh, in_specs=(ad_specs,),
-             out_specs=ad_specs, check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(ad_specs,),
+             out_specs=ad_specs, check_vma=False)
     def grad_return(g):
         # every pod holds its own stage's adapter-grad slice; each client
         # link round-trips that slice (encode -> ship to server -> server

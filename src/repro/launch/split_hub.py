@@ -54,12 +54,13 @@ from repro.core.quantizers import QuantConfig
 from repro.core.split import HubConfig
 from repro.core.split_stage import init_stage_params
 from repro.launch import schedules
+from repro.launch.mesh import make_mesh
 from repro.optim import AdamWConfig, init_opt_state
 
 
 def hub_mesh(n_clients: int, data_shards: int = 2):
     """(pod, data) mesh with one pod per client plus one for the server."""
-    return jax.make_mesh((n_clients + 1, data_shards), ("pod", "data"))
+    return make_mesh((n_clients + 1, data_shards), ("pod", "data"))
 
 
 def init_hub_params(key, cfg: ArchConfig, hub: HubConfig,
@@ -669,6 +670,9 @@ def main(smoke: bool = False) -> Dict:
 if __name__ == "__main__":
     import json
 
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     out = main(smoke="--smoke" in sys.argv)
     os.makedirs(os.path.join(os.path.dirname(__file__), "..", "..", "..",
                              "results"), exist_ok=True)

@@ -58,6 +58,7 @@ from repro.core.quantizers import QuantConfig
 from repro.core.split import SplitConfig
 from repro.core.split_stage import init_stage_params, stage_param_specs
 from repro.launch import schedules
+from repro.launch.mesh import make_mesh
 from repro.optim import AdamWConfig, init_opt_state
 
 
@@ -293,10 +294,10 @@ def train_pipeline(cfg: ArchConfig, mesh, split, opt_cfg: AdamWConfig,
 def _pipeline_mesh(n_stages: int, smoke: bool = False):
     """(pod, data[, model]) mesh with a pod axis of n_stages."""
     if smoke:
-        return jax.make_mesh((n_stages, 2), ("pod", "data"))
+        return make_mesh((n_stages, 2), ("pod", "data"))
     n_dev = len(jax.devices())
     model = max(1, n_dev // (n_stages * 16))
-    return jax.make_mesh((n_stages, 16, model), ("pod", "data", "model"))
+    return make_mesh((n_stages, 16, model), ("pod", "data", "model"))
 
 
 def _micro_batch_sds(n_micro, micro_batch, seq):
@@ -516,7 +517,7 @@ def dryrun_train_adaptive(arch: str = "llama3_2_3b", n_steps: int = 6,
 
     n_stages = 2
     cfg = _homogeneous_cfg(arch, reduced=True, n_stages=n_stages)
-    mesh = jax.make_mesh((n_stages, 2), ("pod", "data"))
+    mesh = make_mesh((n_stages, 2), ("pod", "data"))
     split = SplitConfig(quant=QuantConfig(method="rdfsq", bits=2),
                         learnable_codec=False, n_stages=n_stages)
     pipe = make_pipeline(cfg, n_micro * micro_batch, seq, seed=0)
@@ -607,7 +608,7 @@ def dryrun_train(arch: str = "llama3_2_3b", n_steps: int = 6,
     from repro.data.pipeline import make_pipeline
 
     cfg = _homogeneous_cfg(arch, reduced=True, n_stages=n_stages)
-    mesh = jax.make_mesh((n_stages, 2), ("pod", "data"))
+    mesh = make_mesh((n_stages, 2), ("pod", "data"))
     split = SplitConfig(quant=QuantConfig(method="rdfsq", bits=2),
                         learnable_codec=False, n_stages=n_stages)
     pipe = make_pipeline(cfg, n_micro * micro_batch, seq, seed=0)
@@ -651,7 +652,7 @@ def dryrun_lora_train(arch: str = "llama3_2_3b", n_steps: int = 6,
     from repro.peft import adapter_bytes, adapter_param_count
 
     cfg = _homogeneous_cfg(arch, reduced=True, n_stages=n_stages)
-    mesh = jax.make_mesh((n_stages, 2), ("pod", "data"))
+    mesh = make_mesh((n_stages, 2), ("pod", "data"))
     split = SplitConfig(quant=QuantConfig(method="rdfsq", bits=2),
                         learnable_codec=False, n_stages=n_stages)
     params0 = init_pipeline_params(jax.random.PRNGKey(0), cfg, n_stages,
@@ -729,6 +730,9 @@ def main(smoke: bool = False) -> Dict:
 if __name__ == "__main__":
     import json
 
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     out = main(smoke="--smoke" in sys.argv)
     os.makedirs(os.path.join(os.path.dirname(__file__), "..", "..", "..",
                              "results"), exist_ok=True)

@@ -50,10 +50,15 @@ def main():
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from repro import checkpoint
     from repro.configs import get_config
     from repro.core.quantizers import QuantConfig
     from repro.data.pipeline import make_pipeline
+    from repro.launch.mesh import make_mesh
     from repro.optim import AdamWConfig
     from repro.sharding import batch_pspecs, mesh_axes, state_pspecs
     from repro.sharding import ctx as shard_ctx
@@ -84,7 +89,7 @@ def main():
 
     if args.mesh:
         d, m = (int(v) for v in args.mesh.split("x"))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_mesh((d, m), ("data", "model"))
         axes = mesh_axes(mesh)
         shard_ctx.install(("data",), axes=axes)
         st_specs = state_pspecs(state, axes, fsdp=True)

@@ -104,8 +104,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     kpos = jnp.full((skv + pad_kv,), _FAR, jnp.int32)
     kpos = kpos.at[:min(sq, skv)].set(positions[:min(sq, skv)])
     kpos = jnp.where(jnp.arange(kpos.shape[0]) < kv_valid_len, kpos, _FAR)
-    if attention_ops.resolve_impl(impl) == "pallas" \
-            and attention_ops.compiled_shape_ok(chunk):
+    if attention_ops.resolve_impl(impl) == "pallas":
         out = attention_ops.flash_pallas(qs, kp_arr, vp, qpos, kpos, window,
                                          chunk)
     else:

@@ -38,6 +38,7 @@ import numpy as np
 from repro.configs.base import ArchConfig
 from repro.core import quantizers
 from repro.core.quantizers import QuantConfig
+from repro.kernels import attention_ops, decode_kernel
 from repro.models import transformer as tf
 from repro.models.layers.mlp import mlp_forward
 from repro.serve import decode as sd
@@ -58,13 +59,16 @@ class ServeEngine:
                  split_wire: Optional[QuantConfig] = None,
                  split_wire_budget_bits: Optional[float] = None,
                  split_plan_groups: int = 8,
-                 impl: Optional[str] = None,
                  lora_adapters=None, lora_scale: float = 1.0,
                  weight_quant: Optional[str] = None, wq_group: int = 128,
                  wq_act_order: bool = False,
                  wq_calib: Optional[Dict] = None):
         if cfg.modality == "audio":
             raise NotImplementedError("engine serves text/vlm configs")
+        if attention_ops.resolve_impl() == "pallas":
+            attention_ops.require_compiled(
+                decode_kernel.paged_compiles(page_size),
+                f"paged decode with page_size {page_size}")
         if lora_adapters is not None:
             # SplitLoRA serving: fold the adapters into the base weights
             # ONCE at construction (merge == apply bit-exactly, so merged
@@ -114,15 +118,13 @@ class ServeEngine:
                 raise ValueError("split_wire_budget_bits needs split_wire")
             from repro.core import entropy as entropy_mod
             self._wire_ema = entropy_mod.init_entropy_ema(cfg.d_model)
-        self.impl = impl
         self.pools = paged.init_pools(cfg, n_pages, page_size)
         self.page_pool = PagePool(n_pages)
         n_img = cfg.n_image_tokens if cfg.modality == "vlm" else 0
         self.n_image_tokens = n_img
         self.scheduler = SlotScheduler(n_slots, self.page_pool, page_size,
                                        n_image_tokens=n_img)
-        self._step_fn = paged.compiled_paged_step(cfg, window=window,
-                                                  impl=impl)
+        self._step_fn = paged.compiled_paged_step(cfg, window=window)
         self._rng = jax.random.PRNGKey(seed)
         self._next_rid = 0
         self.stats = dict(wire_bytes=0, prefill_batches=0, decode_ticks=0,

@@ -171,15 +171,16 @@ def test_pick_block_vmem_safe():
     assert pick_block(13) == 13              # odd-but-small: one block
     assert pick_block(3000) == 200           # aligned beats tiny pow2
     assert pick_block(5 * 499) == 499        # no aligned divisor <= cap
-    assert pick_block(100003) is None        # big prime: jnp fallback
+    assert pick_block(100003) is None        # big prime: no block
     for n in (13, 24, 1024, 3000, 32768):
         blk = pick_block(n)
         assert blk is not None and blk <= MAX_BLOCK and n % blk == 0
 
 
 def test_decode_prime_length_falls_back_to_reference():
-    """Cache lengths with no VMEM-safe block must still work on the
-    pallas path (silent jnp fallback inside attention_ops)."""
+    """Cache lengths with no VMEM-safe block still run in interpret mode
+    (one block spans the cache); on TPU they are refused, see
+    test_compiled_kernels_refuse_instead_of_falling_back."""
     b, length, kh, g, d = 1, 2053, 1, 2, 8  # 2053 is prime > MAX_BLOCK
     q = jax.random.normal(KEY, (b, 1, kh * g, d))
     k_cache, v_cache, kpos = _ring_cache(b, length, kh, d, 10)
@@ -246,3 +247,33 @@ def test_env_forced_pallas_decode_matches_full_attention(monkeypatch, bits):
     atol = 2e-4 if bits == 16 else 0.15  # int8 cache is lossy
     np.testing.assert_allclose(np.asarray(step), np.asarray(full),
                                atol=atol)
+
+
+def _refusal(case):
+    if case == "flash_chunk":  # chunk neither 128-aligned nor the whole seq
+        q, k, v = _qkv(96, 4, 2, 16, 16)
+        return lambda: flash_attention(q, k, v, q_chunk=32, kv_chunk=32,
+                                       impl="pallas")
+    if case == "decode_length":  # 1000 slots: no 128-aligned block divides
+        q = jnp.ones((1, 1, 4, 8), jnp.float32)
+        cache = jnp.ones((1, 1000, 2, 8), jnp.float32)
+        kpos = jnp.zeros((1, 1000), jnp.int32)
+        return lambda: decode_attention(q, cache, cache, kpos,
+                                        jnp.zeros((1,), jnp.int32),
+                                        impl="pallas")
+    from repro.configs import get_config
+    from repro.serve.engine import ServeEngine
+    return lambda: ServeEngine(None, get_config("llama3_2_3b").reduced(),
+                               n_slots=1, page_size=1, n_pages=5)
+
+
+@pytest.mark.parametrize("case", ["flash_chunk", "decode_length",
+                                  "engine_page_size"])
+def test_compiled_kernels_refuse_instead_of_falling_back(monkeypatch, case):
+    """On TPU a shape the compiled kernel cannot take raises; it never
+    quietly runs the jnp reference."""
+    monkeypatch.setenv("REPRO_ATTN_IMPL", "pallas")
+    run = _refusal(case)
+    monkeypatch.setattr(attention_ops, "_interpret", lambda: False)
+    with pytest.raises(ValueError, match="impl='jnp'"):
+        run()

@@ -32,6 +32,7 @@ def test_sharded_train_step_matches_single_device():
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config
         from repro.data.pipeline import make_pipeline
+        from repro.launch.mesh import make_mesh
         from repro.optim import AdamWConfig
         from repro.sharding import mesh_axes, state_pspecs, batch_pspecs
         from repro.train.loop import init_state, make_train_step
@@ -46,7 +47,7 @@ def test_sharded_train_step_matches_single_device():
         # single device reference
         s_ref, m_ref = jax.jit(step)(state, batch, key)
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         axes = mesh_axes(mesh)
         st_specs = state_pspecs(state, axes, fsdp=True)
         b_specs = batch_pspecs(batch, ("data",), axes)
@@ -79,16 +80,16 @@ def test_quantized_ship_across_pod_axis():
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.core import QuantConfig, quantized_ship, roundtrip
+        from repro.launch.mesh import make_mesh
 
-        mesh = jax.make_mesh((2, 4), ("pod", "data"))
+        mesh = make_mesh((2, 4), ("pod", "data"))
         qcfg = QuantConfig(method="rdfsq", bits=2)
         perm = [(0, 1), (1, 0)]
 
         # replicate over data so per-sample quantizer stats match the
         # single-device reference (RD-FSQ stats are per local sample)
-        @partial(shard_map, mesh=mesh, in_specs=P("pod", None, None),
+        @partial(jax.shard_map, mesh=mesh, in_specs=P("pod", None, None),
                  out_specs=P("pod", None, None))
         def ship(x):
             return quantized_ship(qcfg, x, "pod", tuple(perm))
@@ -132,13 +133,14 @@ def test_split_pipeline_loss_matches_monolithic():
         from repro.core import quantizers as Q
         from repro.core.quantizers import QuantConfig
         from repro.launch import split_pipeline as sp
+        from repro.launch.mesh import make_mesh
         from repro.models import transformer as tf
         from repro.models.layers import embedding as emb_mod
         from repro.models.layers.norms import rms_norm
         from repro.train.losses import IGNORE, cross_entropy
 
         cfg = sp._homogeneous_cfg("llama3_2_3b", reduced=True)
-        mesh = jax.make_mesh((2, 4), ("pod", "data"))
+        mesh = make_mesh((2, 4), ("pod", "data"))
         key = jax.random.PRNGKey(0)
         params = sp.init_pipeline_params(key, cfg)
         n_micro, mb, seq = 3, 4, 16
@@ -195,6 +197,7 @@ def test_split_pipeline_grad_and_nstage():
         from repro.core.quantizers import QuantConfig
         from repro.core.split import SplitConfig
         from repro.launch import split_pipeline as sp
+        from repro.launch.mesh import make_mesh
         from repro.train.losses import IGNORE
 
         cfg = sp._homogeneous_cfg("llama3_2_3b", reduced=True)
@@ -206,7 +209,7 @@ def test_split_pipeline_grad_and_nstage():
             [tokens[:, :, 1:],
              jnp.full((n_micro, mb, 1), IGNORE, tokens.dtype)], axis=-1)
 
-        mesh = jax.make_mesh((2, 4), ("pod", "data"))
+        mesh = make_mesh((2, 4), ("pod", "data"))
         params = sp.init_pipeline_params(key, cfg)
         qcfg = QuantConfig(method="rdfsq", bits=2)
         gstep = sp.build_pipeline_grad_step(cfg, mesh, qcfg,
@@ -232,7 +235,7 @@ def test_split_pipeline_grad_and_nstage():
         from repro.train.losses import cross_entropy
 
         cfg4 = dataclasses.replace(cfg, n_layers=4)
-        mesh4 = jax.make_mesh((4, 2), ("pod", "data"))
+        mesh4 = make_mesh((4, 2), ("pod", "data"))
         quants = (QuantConfig(method="rdfsq", bits=2),
                   QuantConfig(method="nf", bits=4),
                   QuantConfig(method="rdfsq", bits=2))

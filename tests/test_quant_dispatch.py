@@ -8,7 +8,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import packing
@@ -16,6 +15,7 @@ from repro.core import quantizers as Q
 from repro.core.quantizers import QuantConfig
 from repro.core.split import SplitConfig, compressor_roundtrip, \
     quantized_ship, wire_payload
+from repro.launch.mesh import make_mesh
 
 KEY = jax.random.PRNGKey(0)
 
@@ -162,10 +162,10 @@ def test_unsupported_configs_fall_back_to_jnp():
 
 def _ship_self(qcfg, x):
     """quantized_ship under the identity permutation on a 1-pod mesh."""
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = make_mesh((1,), ("pod",))
 
-    @partial(shard_map, mesh=mesh, in_specs=P(), out_specs=P(),
-             check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=P(), out_specs=P(),
+             check_vma=False)
     def ship(x):
         return quantized_ship(qcfg, x, "pod", ((0, 0),))
 
@@ -202,10 +202,10 @@ def test_ship_wire_dtype_pinned():
     widened float — XLA likes to reorder converts across collectives."""
     import re
     qcfg = QuantConfig(method="identity")
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = make_mesh((1,), ("pod",))
 
-    @partial(shard_map, mesh=mesh, in_specs=P(), out_specs=P(),
-             check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=P(), out_specs=P(),
+             check_vma=False)
     def ship(x):
         return quantized_ship(qcfg, x, "pod", ((0, 0),))
 
