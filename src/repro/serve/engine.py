@@ -25,6 +25,18 @@ matching ``WireLink.fwd_wire_bytes`` static accounting.  A grouped
 activations as a mixed-precision ``GroupedPayload``;
 ``split_wire_budget_bits`` additionally re-plans the widths between
 prefills from a per-channel entropy EMA of the connector features.
+
+Tracing: each phase of ``step()`` runs inside a
+``jax.profiler.TraceAnnotation`` (``engine.step`` around ``engine.admit``,
+``engine.prefill.inputs``, ``engine.wire``, ``engine.prefill.launch``,
+``engine.prefill.fetch``, ``engine.tick.inputs``, ``engine.tick.launch``,
+``engine.tick.fetch``, ``engine.pick`` and ``engine.emit``), which records
+only while a profiler session runs, on the clock of the device trace.
+``*.launch`` only dispatches; ``*.fetch`` is where the host waits for the
+device and copies the logits back.  ``stats`` counts the prefill rows,
+positions computed and positions that hold a prompt, and the programs
+lowered (``compiles``); ``Request`` keeps its submit and admit times on
+the clock of ``arrival_time`` and ``emit_times``.
 """
 from __future__ import annotations
 
@@ -34,6 +46,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ArchConfig
 from repro.core import quantizers
@@ -45,6 +58,7 @@ from repro.serve import decode as sd
 from repro.serve import paged
 from repro.serve.pool import PagePool
 from repro.serve.scheduler import Request, SlotScheduler
+from repro.utils import compile_cache
 
 __all__ = ["ServeEngine"]
 
@@ -129,7 +143,10 @@ class ServeEngine:
         self._next_rid = 0
         self.stats = dict(wire_bytes=0, prefill_batches=0, decode_ticks=0,
                           tokens_emitted=0, admitted=0, retired=0,
+                          prefill_rows=0, prefill_positions=0,
+                          prefill_real_positions=0, compiles=0,
                           page_table_buckets=set())
+        self._lowerings0 = compile_cache.lowerings()
         if self.wq_report is not None:
             self.stats["weight_bytes_dense"] = sum(
                 d for d, _ in self.wq_report.values())
@@ -148,7 +165,8 @@ class ServeEngine:
         self.scheduler.submit(Request(rid=rid, tokens=list(tokens),
                                       max_new=max_new,
                                       image_embeds=image_embeds,
-                                      arrival_time=arrival_time))
+                                      arrival_time=arrival_time,
+                                      submit_time=time.perf_counter()))
         return rid
 
     @property
@@ -161,11 +179,12 @@ class ServeEngine:
     # -- sampling -------------------------------------------------------
     def _pick(self, last_logits: np.ndarray) -> np.ndarray:
         """(m, V) -> (m,) token ids (greedy, or temperature sampling)."""
-        if self.temperature <= 0.0:
-            return np.argmax(last_logits, axis=-1)
-        self._rng, sub = jax.random.split(self._rng)
-        return np.asarray(jax.random.categorical(
-            sub, jnp.asarray(last_logits) / self.temperature, axis=-1))
+        with TraceAnnotation("engine.pick"):
+            if self.temperature <= 0.0:
+                return np.argmax(last_logits, axis=-1)
+            self._rng, sub = jax.random.split(self._rng)
+            return np.asarray(jax.random.categorical(
+                sub, jnp.asarray(last_logits) / self.temperature, axis=-1))
 
     def _maybe_finish(self, req: Request, tok: int) -> None:
         if self.eos_id is not None and tok == self.eos_id:
@@ -222,50 +241,60 @@ class ServeEngine:
         lb = npb * pg
         rows = paged.next_pow2(len(admitted))
         lp = lb - n_img  # token length such that positions cover exactly lb
-        tokens = np.zeros((rows, lp), np.int32)
-        for i, r in enumerate(admitted):
-            tokens[i, :len(r.tokens)] = r.tokens
-        batch: Dict = dict(tokens=jnp.asarray(tokens))
-        if cfg.modality == "vlm":
-            imgs = np.stack(
-                [np.asarray(r.image_embeds) for r in admitted]
-                + [np.zeros_like(np.asarray(admitted[0].image_embeds))]
-                * (rows - len(admitted)))
-            if self.split_wire is not None:
+        with TraceAnnotation("engine.prefill.inputs", rows=rows,
+                             positions=rows * lb):
+            tokens = np.zeros((rows, lp), np.int32)
+            for i, r in enumerate(admitted):
+                tokens[i, :len(r.tokens)] = r.tokens
+            batch: Dict = dict(tokens=jnp.asarray(tokens))
+            if cfg.modality == "vlm":
+                batch["image_embeds"] = jnp.asarray(np.stack(
+                    [np.asarray(r.image_embeds) for r in admitted]
+                    + [np.zeros_like(np.asarray(admitted[0].image_embeds))]
+                    * (rows - len(admitted))))
+            # scatter the ring caches into each request's physical pages;
+            # logical pages past a row's reservation (and the dummy rows)
+            # go to the trash page, right-padding is masked to pos = -1.
+            page_rows = np.zeros((rows, npb), np.int32)
+            valid_len = np.zeros((rows,), np.int32)
+            for i, r in enumerate(admitted):
+                row = (r.pages + [0] * npb)[:npb]
+                page_rows[i] = row
+                valid_len[i] = n_img + plens[i]
+            page_rows, valid_len = (jnp.asarray(page_rows),
+                                    jnp.asarray(valid_len))
+        if "image_embeds" in batch and self.split_wire is not None:
+            # popped, so the embeddings are freed once they are shipped
+            with TraceAnnotation("engine.wire") as span:
+                sent = self.stats["wire_bytes"]
                 batch["image_features"] = self._ship_image_features(
-                    jnp.asarray(imgs))
-            else:
-                batch["image_embeds"] = jnp.asarray(imgs)
-        self._rng, prefill_rng = jax.random.split(self._rng)
-        logits, caches = sd.prefill(self.params, cfg, batch, lb,
-                                    window=self.window, rng=prefill_rng)
-        # scatter the ring caches into each request's physical pages;
-        # logical pages past a row's reservation (and the dummy rows) go
-        # to the trash page, right-padding is masked to pos = -1.
-        page_rows = np.zeros((rows, npb), np.int32)
-        valid_len = np.zeros((rows,), np.int32)
-        for i, r in enumerate(admitted):
-            row = (r.pages + [0] * npb)[:npb]
-            page_rows[i] = row
-            valid_len[i] = n_img + plens[i]
-        self.pools = paged.insert_prefill(self.pools, caches,
-                                          jnp.asarray(page_rows),
-                                          jnp.asarray(valid_len))
+                    batch.pop("image_embeds"))
+                span.set_metadata(wire_bytes=self.stats["wire_bytes"] - sent)
+        with TraceAnnotation("engine.prefill.launch"):
+            self._rng, prefill_rng = jax.random.split(self._rng)
+            logits, caches = sd.prefill(self.params, cfg, batch, lb,
+                                        window=self.window, rng=prefill_rng)
+            self.pools = paged.insert_prefill(self.pools, caches, page_rows,
+                                              valid_len)
         # first emitted token: the pick at each row's LAST REAL position
         # (right-padded rows must not read the pad tail's logits).
-        lg = np.asarray(logits)
-        last = lg[np.arange(len(admitted)),
-                  [n_img + p - 1 for p in plens]]
+        with TraceAnnotation("engine.prefill.fetch"):
+            lg = np.asarray(logits)
+            last = lg[np.arange(len(admitted)),
+                      [n_img + p - 1 for p in plens]]
         toks = self._pick(last)
-        now = time.perf_counter()
-        for r, tok in zip(admitted, toks):
-            r.out.append(int(tok))
-            r.prefill_time = now
-            r.emit_times.append(now)
-            self.stats["tokens_emitted"] += 1
-            self._maybe_finish(r, int(tok))
+        with TraceAnnotation("engine.emit"):
+            now = time.perf_counter()
+            for r, tok in zip(admitted, toks):
+                r.out.append(int(tok))
+                r.emit_times.append(now)
+                self.stats["tokens_emitted"] += 1
+                self._maybe_finish(r, int(tok))
         self.stats["prefill_batches"] += 1
         self.stats["admitted"] += len(admitted)
+        self.stats["prefill_rows"] += rows
+        self.stats["prefill_positions"] += rows * lb
+        self.stats["prefill_real_positions"] += sum(n_img + p for p in plens)
 
     # -- decode tick ----------------------------------------------------
     def _decode_tick(self, active: List[Request]) -> None:
@@ -273,38 +302,51 @@ class ServeEngine:
         s = self.scheduler.n_slots
         npp = paged.next_pow2(max(r.qpos // pg + 1 for r in active))
         self.stats["page_table_buckets"].add(npp)
-        tokens = np.zeros((s, 1), np.int32)
-        qpos = np.full((s,), -1, np.int32)
-        page_table = np.full((s, npp), -1, np.int32)
-        for r in active:
-            tokens[r.slot, 0] = r.out[-1]
-            qpos[r.slot] = r.qpos
-            row = r.pages[:npp]
-            page_table[r.slot, :len(row)] = row
-        logits, self.pools = self._step_fn(
-            self.params, self.pools, dict(tokens=jnp.asarray(tokens)),
-            jnp.asarray(qpos), jnp.asarray(page_table))
-        last = np.asarray(logits)[:, -1]
+        with TraceAnnotation("engine.tick.inputs", active=len(active),
+                             npp=npp):
+            tokens = np.zeros((s, 1), np.int32)
+            qpos = np.full((s,), -1, np.int32)
+            page_table = np.full((s, npp), -1, np.int32)
+            for r in active:
+                tokens[r.slot, 0] = r.out[-1]
+                qpos[r.slot] = r.qpos
+                row = r.pages[:npp]
+                page_table[r.slot, :len(row)] = row
+            tokens, qpos, page_table = (jnp.asarray(tokens),
+                                        jnp.asarray(qpos),
+                                        jnp.asarray(page_table))
+        with TraceAnnotation("engine.tick.launch"):
+            logits, self.pools = self._step_fn(
+                self.params, self.pools, dict(tokens=tokens), qpos,
+                page_table)
+        with TraceAnnotation("engine.tick.fetch"):
+            last = np.asarray(logits)[:, -1]
         toks = self._pick(last)
-        now = time.perf_counter()
-        for r in active:
-            tok = int(toks[r.slot])
-            r.out.append(tok)
-            r.qpos += 1
-            r.emit_times.append(now)
-            self.stats["tokens_emitted"] += 1
-            self._maybe_finish(r, tok)
+        with TraceAnnotation("engine.emit"):
+            now = time.perf_counter()
+            for r in active:
+                tok = int(toks[r.slot])
+                r.out.append(tok)
+                r.qpos += 1
+                r.emit_times.append(now)
+                self.stats["tokens_emitted"] += 1
+                self._maybe_finish(r, tok)
         self.stats["decode_ticks"] += 1
 
     # -- main loop ------------------------------------------------------
     def step(self) -> None:
         """One engine tick: admit (+ prefill) then decode every slot."""
-        admitted = self.scheduler.admit()
-        if admitted:
-            self._prefill(admitted)
-        active = self.scheduler.active
-        if active:
-            self._decode_tick(active)
+        with TraceAnnotation("engine.step"):
+            with TraceAnnotation("engine.admit") as span:
+                admitted = self.scheduler.admit()
+                span.set_metadata(admitted=len(admitted))
+            if admitted:
+                self._prefill(admitted)
+            active = self.scheduler.active
+            if active:
+                self._decode_tick(active)
+            self.stats["compiles"] = (compile_cache.lowerings()
+                                      - self._lowerings0)
 
     def run(self) -> Dict[int, List[int]]:
         """Drive until every submitted request finished."""

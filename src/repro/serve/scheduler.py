@@ -10,6 +10,7 @@ admission and retirement never stall the other slots' decodes.
 from __future__ import annotations
 
 import dataclasses
+import time
 from collections import deque
 from typing import Any, Deque, Dict, List, Optional
 
@@ -29,6 +30,10 @@ class Request:
     max_new: int
     image_embeds: Optional[Any] = None     # (n_img, d_vision) for VLM cfgs
     arrival_time: float = 0.0
+    # ``time.perf_counter()`` at ``ServeEngine.submit`` and at admission;
+    # the clock of ``arrival_time`` and ``emit_times``
+    submit_time: Optional[float] = None
+    admit_time: Optional[float] = None
 
     # runtime state (owned by the scheduler/engine)
     state: str = WAITING
@@ -39,7 +44,6 @@ class Request:
     finish_reason: str = ""
     # per-token wall-clock emission times (benchmark latency accounting)
     emit_times: List[float] = dataclasses.field(default_factory=list)
-    prefill_time: float = 0.0
 
     def prompt_len(self, n_image_tokens: int = 0) -> int:
         n_img = n_image_tokens if self.image_embeds is not None else 0
@@ -103,6 +107,7 @@ class SlotScheduler:
             req.slot = free_slots.pop(0)
             req.state = ACTIVE
             req.qpos = req.prompt_len(self.n_image_tokens)
+            req.admit_time = time.perf_counter()
             self.slots[req.slot] = req
             admitted.append(req)
         return admitted
