@@ -501,12 +501,17 @@ def _run_segments(params, cfg: ArchConfig, side: str, segs, x, *, positions,
 
 def forward(params, cfg: ArchConfig, batch: Dict, *,
             rng: Optional[jax.Array] = None, window: Optional[int] = None,
-            collect_cache: Optional[int] = None):
+            collect_cache: Optional[int] = None,
+            last_positions: Optional[jnp.ndarray] = None):
     """Full-sequence forward (train / prefill).
 
     Returns (logits, aux) or (logits, aux, caches) when
     ``collect_cache`` (a cache length) is given.
     aux = {commit, load_balance, router_z, drop_fraction}.
+
+    ``last_positions`` ((B,) int32, runtime data) applies the final norm
+    and the head only at one position per row: logits are then (B, V)
+    (or (B, K, V) for audio) instead of (B, S, V).
     """
     x = shard_ctx.constrain(_embed_inputs(params, cfg, batch), "hidden")
     emb0 = x
@@ -532,9 +537,14 @@ def forward(params, cfg: ArchConfig, batch: Dict, *,
         params, cfg, "server", server_segs, x, positions=positions,
         window=window, emb0=emb0, collect_cache=collect_cache)
 
+    if last_positions is not None:
+        x = jnp.take_along_axis(
+            x, last_positions.astype(jnp.int32)[:, None, None], axis=1)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = emb_mod.head_logits(params["head"], x)
-    if logits.ndim == 3:
+    if last_positions is not None:
+        logits = logits[:, 0]
+    elif logits.ndim == 3:
         logits = shard_ctx.constrain(logits, "logits")
     aux = {k: aux_c[k] + aux_s[k] for k in aux_c}
     aux["commit"] = commit

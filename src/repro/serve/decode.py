@@ -75,10 +75,11 @@ def _compiled_prefill(cfg: ArchConfig, cache_len: int,
                       window: Optional[int], attn_impl: str) -> Callable:
     del attn_impl  # cache key only; the traced code reads the env var
 
-    def _prefill(params, batch, rng):
+    def _prefill(params, batch, rng, last_positions):
         logits, _aux, caches = tf.forward(params, cfg, batch, rng=rng,
                                           window=window,
-                                          collect_cache=cache_len)
+                                          collect_cache=cache_len,
+                                          last_positions=last_positions)
         return logits, caches
 
     return jax.jit(_prefill)
@@ -86,15 +87,23 @@ def _compiled_prefill(cfg: ArchConfig, cache_len: int,
 
 def prefill(params, cfg: ArchConfig, batch: Dict, cache_len: int, *,
             window: Optional[int] = None,
-            rng: Optional[jax.Array] = None):
+            rng: Optional[jax.Array] = None,
+            last_positions: Optional[jnp.ndarray] = None):
     """Run the full-sequence pass and return (logits, caches).
+
+    Logits are (B, S, V), every position's, or with ``last_positions``
+    ((B,) int32) only those rows' positions, (B, V): the final norm and
+    the head then run at B positions, and a caller that right-pads rows
+    copies back one vocabulary row each.  The positions are traced as
+    data, so each one shares the program of its shape.  The caches are
+    the same either way.
 
     Jitted and cached per (cfg, cache_len, window, backend): the serving
     engine prefills every admission wave through here, so an unjitted
     (op-by-op) forward would dominate its tick time."""
     fn = _compiled_prefill(cfg, cache_len, window,
                            attention_ops.resolve_impl(None))
-    return fn(params, batch, rng)
+    return fn(params, batch, rng, last_positions)
 
 
 def generate(params, cfg: ArchConfig, batch: Dict, *, n_new: int,

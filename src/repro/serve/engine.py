@@ -12,7 +12,11 @@ admissions) -> one decode tick over every active slot.  Prefill runs as
 its own batched forward (``serve/decode.prefill`` on a bucketed shape),
 so admission never recompiles or stalls the in-flight decode step; the
 prefilled ring caches are scattered into the paged pools by
-``paged.insert_prefill`` (pools donated, in-place).
+``paged.insert_prefill`` (pools donated, in-place).  The prefill is given
+each row's last real position and returns the logits there only,
+(rows, V): the host copies back one vocabulary row per admitted request
+(and per dummy row that pads the wave to a power of two), never the
+(rows, bucket, V) logits of every position.
 
 Split-serve mode (``split_wire=QuantConfig(...)``): the client is assumed
 to hold the vision tower + connector; the engine runs the connector
@@ -33,9 +37,11 @@ Tracing: each phase of ``step()`` runs inside a
 ``engine.tick.fetch``, ``engine.pick`` and ``engine.emit``), which records
 only while a profiler session runs, on the clock of the device trace.
 ``*.launch`` only dispatches; ``*.fetch`` is where the host waits for the
-device and copies the logits back.  ``stats`` counts the prefill rows,
-positions computed and positions that hold a prompt, and the programs
-lowered (``compiles``); ``Request`` keeps its submit and admit times on
+device and copies the logits back; ``engine.prefill.fetch`` carries the
+bytes it copies.  ``stats`` counts the prefill rows, positions computed
+and positions that hold a prompt, the bytes of logits the prefills copied
+back (``prefill_fetch_bytes``), and the programs lowered
+(``compiles``); ``Request`` keeps its submit and admit times on
 the clock of ``arrival_time`` and ``emit_times``.
 """
 from __future__ import annotations
@@ -144,7 +150,8 @@ class ServeEngine:
         self.stats = dict(wire_bytes=0, prefill_batches=0, decode_ticks=0,
                           tokens_emitted=0, admitted=0, retired=0,
                           prefill_rows=0, prefill_positions=0,
-                          prefill_real_positions=0, compiles=0,
+                          prefill_real_positions=0,
+                          prefill_fetch_bytes=0, compiles=0,
                           page_table_buckets=set())
         self._lowerings0 = compile_cache.lowerings()
         if self.wq_report is not None:
@@ -261,6 +268,9 @@ class ServeEngine:
                 row = (r.pages + [0] * npb)[:npb]
                 page_rows[i] = row
                 valid_len[i] = n_img + plens[i]
+            # the first emitted token is picked at each row's LAST REAL
+            # position (never the pad tail's); dummy rows read position 0
+            last_pos = jnp.asarray(np.maximum(valid_len - 1, 0))
             page_rows, valid_len = (jnp.asarray(page_rows),
                                     jnp.asarray(valid_len))
         if "image_embeds" in batch and self.split_wire is not None:
@@ -273,15 +283,13 @@ class ServeEngine:
         with TraceAnnotation("engine.prefill.launch"):
             self._rng, prefill_rng = jax.random.split(self._rng)
             logits, caches = sd.prefill(self.params, cfg, batch, lb,
-                                        window=self.window, rng=prefill_rng)
+                                        window=self.window, rng=prefill_rng,
+                                        last_positions=last_pos)
             self.pools = paged.insert_prefill(self.pools, caches, page_rows,
                                               valid_len)
-        # first emitted token: the pick at each row's LAST REAL position
-        # (right-padded rows must not read the pad tail's logits).
-        with TraceAnnotation("engine.prefill.fetch"):
-            lg = np.asarray(logits)
-            last = lg[np.arange(len(admitted)),
-                      [n_img + p - 1 for p in plens]]
+        with TraceAnnotation("engine.prefill.fetch", bytes=logits.nbytes):
+            last = np.asarray(logits)[:len(admitted)]
+        self.stats["prefill_fetch_bytes"] += logits.nbytes
         toks = self._pick(last)
         with TraceAnnotation("engine.emit"):
             now = time.perf_counter()

@@ -24,7 +24,7 @@ SPANS = {
     "engine.prefill.inputs": ("rows", "positions"),
     "engine.wire": ("wire_bytes",),
     "engine.prefill.launch": (),
-    "engine.prefill.fetch": (),
+    "engine.prefill.fetch": ("bytes",),
     "engine.tick.inputs": ("active", "npp"),
     "engine.tick.launch": (),
     "engine.tick.fetch": (),
@@ -118,6 +118,8 @@ def test_span_stats_and_counts(traced):
         st["prefill_rows"], st["prefill_positions"])
     (_, _, wire), = events["engine.wire"]
     assert wire["wire_bytes"] == st["wire_bytes"] > 0
+    (_, _, fetch), = events["engine.prefill.fetch"]
+    assert fetch["bytes"] == st["prefill_fetch_bytes"] > 0
     actives = [d["active"] for _, _, d in events["engine.tick.inputs"]]
     assert actives == sorted(actives, reverse=True) and actives[0] == 3
 
@@ -131,6 +133,15 @@ def test_prefill_position_counters_by_hand(traced):
     assert st["prefill_rows"] == 4
     assert st["prefill_positions"] == 4 * 32
     assert st["prefill_real_positions"] == 3 * 16 + 5 + 7 + 9
+
+
+def test_prefill_fetch_bytes_by_hand(traced):
+    """The prefill copies back one vocabulary row per prefill row: four
+    rows of 512 logits, not four rows of 32 positions of them."""
+    st, cfg = traced["stats"], traced["cfg"]
+    itemsize = np.dtype(tf.cdtype(cfg)).itemsize
+    assert cfg.vocab_size == 512
+    assert st["prefill_fetch_bytes"] == 4 * 512 * itemsize
 
 
 def test_request_times_in_order(traced):

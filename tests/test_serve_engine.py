@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.core import QuantConfig, SplitConfig
 from repro.core.split import WireLink
 from repro.kernels import attention_ops, attention_ref
 from repro.models import transformer as tf
@@ -16,6 +17,7 @@ from repro.serve import decode as sd
 from repro.serve.engine import ServeEngine
 from repro.serve.pool import PagePool
 from repro.serve.scheduler import Request, SlotScheduler
+from repro.utils import compile_cache
 
 
 def _params(cfg, seed=0):
@@ -293,6 +295,154 @@ def test_engine_vlm_lockstep_and_split_serve_wire_bytes():
     link = WireLink(src=0, dst=1, quant=cfg.split.quant)
     sds = jax.ShapeDtypeStruct((b, n_img, cfg.d_model), tf.cdtype(cfg))
     assert eng.stats["wire_bytes"] == link.fwd_wire_bytes(sds)
+
+
+# ---------------------------------------------------------------------------
+# prefill at each row's last real position
+# ---------------------------------------------------------------------------
+
+def _ragged_prefill_batch(cfg, plens, rows, lp, seed):
+    """Right-padded prompts of lengths ``plens`` in ``rows`` rows of
+    ``lp`` tokens; rows past ``plens`` are dummies (zeros)."""
+    rng = np.random.default_rng(seed)
+    tokens = np.zeros((rows, lp), np.int32)
+    for i, p in enumerate(plens):
+        tokens[i, :p] = rng.integers(1, cfg.vocab_size, p)
+    batch = dict(tokens=jnp.asarray(tokens))
+    if cfg.modality == "vlm":
+        img = rng.normal(size=(rows, cfg.n_image_tokens, cfg.d_vision))
+        img[len(plens):] = 0.0
+        batch["image_embeds"] = jnp.asarray(img.astype(np.float32))
+    return batch
+
+
+@pytest.mark.parametrize("arch", ["llama3_2_3b", "tinyllava", "zamba2_2_7b"])
+def test_prefill_last_positions_gathers_full_logits(arch):
+    """``last_positions`` returns the full prefill's logits at those
+    positions, (B, V), and the same caches."""
+    cfg = get_config(arch).reduced()
+    params = _params(cfg)
+    n_img = cfg.n_image_tokens if cfg.modality == "vlm" else 0
+    plens, rows, lp, cache_len = [5, 12, 8], 4, 12, n_img + 12
+    batch = _ragged_prefill_batch(cfg, plens, rows, lp, seed=11)
+    last = np.array([n_img + p - 1 for p in plens] + [0], np.int32)
+    rng = jax.random.PRNGKey(3)
+    full, caches = sd.prefill(params, cfg, batch, cache_len, rng=rng)
+    got, got_caches = sd.prefill(params, cfg, batch, cache_len, rng=rng,
+                                 last_positions=jnp.asarray(last))
+    assert full.shape == (rows, n_img + lp, cfg.vocab_size)
+    assert got.shape == (rows, cfg.vocab_size)
+    # the head over B rows and over B x S rows may sum in another order
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(full)[np.arange(rows), last],
+                               rtol=1e-5, atol=1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(caches),
+                    jax.tree_util.tree_leaves(got_caches)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_prefill_last_positions_are_data_not_a_new_program():
+    """Other positions of the same shape reuse the traced program."""
+    cfg = get_config("llama3_2_3b").reduced()
+    params = _params(cfg)
+    batch = _ragged_prefill_batch(cfg, [3, 7], 2, 8, seed=2)
+    sd.prefill(params, cfg, batch, 8,
+               last_positions=jnp.asarray([2, 6], jnp.int32))
+    before = compile_cache.lowerings()
+    sd.prefill(params, cfg, batch, 8,
+               last_positions=jnp.asarray([0, 7], jnp.int32))
+    assert compile_cache.lowerings() == before
+
+
+def _wave_features(params, cfg, wire, imgs, rows):
+    """The engine's split-serve wave: connector over the admitted images
+    and zero dummy rows, one encode/decode round trip for the wave."""
+    from repro.core import quantizers
+    from repro.models.layers.mlp import mlp_forward
+
+    pad = np.zeros((rows - len(imgs),) + imgs.shape[1:], imgs.dtype)
+    feats = mlp_forward(params["connector"],
+                        jnp.asarray(np.concatenate([imgs, pad]))
+                        .astype(tf.cdtype(cfg)))
+    return quantizers.decode(wire, quantizers.encode(wire, feats))
+
+
+def _no_cut(cfg):
+    """No compressor at the cut: its per-sample statistics would see a
+    row's right padding, which an unpadded ``generate`` never does."""
+    return dataclasses.replace(
+        cfg, split=SplitConfig(quant=QuantConfig(method="identity"),
+                               learnable_codec=False, enabled=False))
+
+
+@pytest.mark.parametrize("arch,plens,split", [
+    ("tinyllava", [16, 16, 16], True),
+    ("tinyllava", [5, 17, 9], False),
+    ("llama3_2_3b", [5, 17, 9], False),
+])
+def test_engine_prefill_fetches_rows_by_vocab(arch, plens, split):
+    """Three requests in a wave of four rows (one dummy row): the prefill
+    copies back rows x V logits, and every request's tokens equal greedy
+    ``generate`` on its own prompt.  Split-serve ships the images over
+    the wire (``generate`` gets the reconstruction of the same wave) and
+    keeps the cut's compressor, so its prompts fill the bucket; prompts
+    of different lengths are right-padded, and run without the cut."""
+    cfg = get_config(arch).reduced()
+    if not split:
+        cfg = _no_cut(cfg)
+    params = _params(cfg)
+    n_img = cfg.n_image_tokens if cfg.modality == "vlm" else 0
+    pg, n_new = 8, 4
+    rng = np.random.default_rng(9)
+    toks = [rng.integers(1, cfg.vocab_size, p).astype(np.int32)
+            for p in plens]
+    imgs = rng.normal(size=(len(plens), n_img, cfg.d_vision)
+                      ).astype(np.float32)
+    wire = cfg.split.quant if split else None
+    n_pages = 1 + 4 * (-(-(n_img + max(plens) + n_new) // pg))
+    eng = ServeEngine(params, cfg, n_slots=4, page_size=pg, n_pages=n_pages,
+                      split_wire=wire)
+    rids = [eng.submit(list(t), max_new=n_new,
+                       image_embeds=imgs[i] if n_img else None)
+            for i, t in enumerate(toks)]
+    res = eng.run()
+
+    rows = eng.stats["prefill_rows"]
+    assert eng.stats["prefill_batches"] == 1 and rows == 4
+    itemsize = jnp.dtype(tf.cdtype(cfg)).itemsize
+    assert eng.stats["prefill_fetch_bytes"] == rows * cfg.vocab_size * itemsize
+    feats = _wave_features(params, cfg, wire, imgs, rows) if split else None
+    for i, t in enumerate(toks):
+        batch = dict(tokens=jnp.asarray(t[None]))
+        if split:
+            batch["image_features"] = feats[i:i + 1]
+        elif n_img:
+            batch["image_embeds"] = jnp.asarray(imgs[i:i + 1])
+        ref = np.asarray(sd.generate(params, cfg, batch, n_new=n_new,
+                                     cache_len=n_img + len(t) + n_new))
+        assert res[rids[i]] == list(ref[0]), i
+
+
+def test_engine_prefill_fetch_bytes_sum_over_waves():
+    """Each prefill wave adds its rows x V x itemsize."""
+    cfg = get_config("llama3_2_3b").reduced()
+    eng = ServeEngine(_params(cfg), cfg, n_slots=2, page_size=4,
+                      n_pages=1 + 10)
+    rng = np.random.default_rng(4)
+    for _ in range(5):
+        eng.submit(list(rng.integers(1, cfg.vocab_size,
+                                     int(rng.integers(2, 10)))),
+                   max_new=int(rng.integers(1, 5)))
+    waves = []
+    while not eng.idle:
+        rows = eng.stats["prefill_rows"]
+        eng.step()
+        if eng.stats["prefill_rows"] != rows:
+            waves.append(eng.stats["prefill_rows"] - rows)
+    itemsize = jnp.dtype(tf.cdtype(cfg)).itemsize
+    assert len(waves) >= 2
+    assert eng.stats["prefill_fetch_bytes"] == sum(
+        r * cfg.vocab_size * itemsize for r in waves)
 
 
 # ---------------------------------------------------------------------------
