@@ -3,8 +3,8 @@
 program's, and for some seeds the float8 control's.
 
     python3 bench/calibrate.py --workload <cell> --seeds 1,2,3 \
-        --seconds 8 [--control-seeds 1,2] [--fault half_batch] \
-        [--dump readings.jsonl]
+        --seconds 8 [--control-seeds 1,2] [--faults half_batch,...] \
+        [--fault-seeds 1,2] [--dump readings.jsonl]
 
 Each seed builds the cell anew (weights, inputs, engine or state), runs a
 short window at the cell's own load through the same driver as
@@ -12,8 +12,12 @@ short window at the cell's own load through the same driver as
 seeds in ``--control-seeds`` also the float8 control's.  ``--dump``
 appends each seed's raw readings (a training cell's losses and leaf
 norms, the program's and the reference's; a serving cell's gap at each
-checked position) to a JSON-lines file.  ``--fault``
-plants one of ``bench/harness/faults.py`` under the timed path.  The
+checked position) to a JSON-lines file.  For each seed in
+``--fault-seeds``, each fault of ``--faults`` (the driver's own
+``FAULTS``, else ``bench/harness/faults.py``'s) is then planted under the
+timed path in turn for one more run of that seed, whose numbers are read
+against the seed's reference (a training driver keeps it; a serving one
+computes it anew), and the fault is taken out again.  The
 limits in ``cells/<cell>.json`` are set from these readings (see
 ``PERF.md``); scored runs never run this.
 """
@@ -34,8 +38,9 @@ def main(argv=None) -> int:
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--control-seeds", default="")
-    ap.add_argument("--fault", default="",
-                    help="plant a fault of bench/harness/faults.py first")
+    ap.add_argument("--faults", default="",
+                    help="faults to plant, one run each, for --fault-seeds")
+    ap.add_argument("--fault-seeds", default="")
     ap.add_argument("--dump", default="",
                     help="append each seed's raw readings to this file")
     args = ap.parse_args(argv)
@@ -48,15 +53,14 @@ def main(argv=None) -> int:
     devices = device.accelerators(cell.chips)
     peaks = spec.peaks(devices[0].device_kind, ROOT)
     device.enable_compile_cache()
-    if args.fault:
-        faults.FAULTS[args.fault](setattr)
-    drv = runner.DRIVERS[cell.kind]
+    drv = runner.driver(cell.kind)
+    plant = {name: getattr(drv, "FAULTS", {}).get(name) or faults.FAULTS[name]
+             for name in args.faults.split(",") if name}
     control = {int(s) for s in args.control_seeds.split(",") if s}
+    fault_seeds = {int(s) for s in args.fault_seeds.split(",") if s}
     for seed in [int(s) for s in args.seeds.split(",")]:
         t0 = time.perf_counter()
-        run = runner.Run(cell=cell, seed=seed, sizes=spec.sizes(cell.config),
-                         arch=spec.arch_config(cell.config), peaks=peaks,
-                         devices=devices)
+        run = runner.make_run(cell, seed, devices, peaks)
         built = drv.build(run)
         counters = drv.window(run, built, args.seconds)
         line = dict(seed=seed, program=drv.check(run, built, counters),
@@ -64,6 +68,10 @@ def main(argv=None) -> int:
                     failed=drv.failed(counters))
         if seed in control:
             line["control"] = drv.control(run, built, counters)
+        if seed in fault_seeds:
+            line["faults"] = {name: _fault_run(drv, run, built, fault,
+                                               args.seconds)
+                              for name, fault in plant.items()}
         line["seconds"] = time.perf_counter() - t0
         print(json.dumps(line), flush=True)
         if args.dump:
@@ -73,6 +81,27 @@ def main(argv=None) -> int:
                 f.write(json.dumps(dict(seed=seed, **raw)) + "\n")
         del built, counters
     return 0
+
+
+def _fault_run(drv, run, sound, fault, seconds):
+    """The numbers of one run with ``fault`` planted, against the sound
+    run's reference; the fault is taken out again after."""
+    undo = []
+
+    def patch(obj, name, value):
+        undo.append((obj, name, getattr(obj, name)))
+        setattr(obj, name, value)
+
+    fault(patch)
+    try:
+        built = drv.build(run)
+        counters = drv.window(run, built, seconds)
+        if "reference" in sound:
+            built["reference"] = sound["reference"]
+        return drv.check(run, built, counters)
+    finally:
+        for obj, name, value in reversed(undo):
+            setattr(obj, name, value)
 
 
 if __name__ == "__main__":

@@ -1,13 +1,37 @@
 """One run of one cell: set-up, window, check, result line.
 
-The kind of the cell's traffic mix picks the driver (``train_cell`` or
-``serve_cell``); everything else is data.  With ``trace`` the window
-runs under the JAX profiler, the trace is reduced (``trace.py``) and the
-cell's per-layer metric readers (``bench/metrics/<name>.py``) read it.
+The kind of the cell's traffic mix picks the driver, the module
+``bench/harness/<kind>_cell.py``; the cell's configuration picks its model
+module (``spec.model_module``); everything else is data.  With ``trace``
+the window runs under the JAX profiler, the trace is reduced
+(``trace.py``) and the cell's per-layer metric readers
+(``bench/metrics/<name>.py``) read it.
+
+A cell whose trace would hold too many device ops to reduce within a
+run's time limit sets ``trace_seconds``: its traced run's window is
+that long, or ``seconds`` where that is shorter.
+
+A driver provides, each taking the ``Run`` first where it needs it:
+
+* ``build(run) -> built``: everything up to the window (weights from the
+  seed, compiled programs, warm-up, the checked steps); counted as set-up;
+* ``window(run, built, seconds) -> counters``: the measured loop;
+* ``program_texts(run, built)``: the compiled HLO of the programs the
+  window ran, for the trace reduction;
+* ``end_to_end(counters)``: the cell's end-to-end metrics but ``setup_s``;
+* ``check(run, built, counters)`` and ``control(run, built, counters)``:
+  the numbers compared with the plain reference, of the program and of
+  the lower-precision control;
+* ``attempted(counters)`` and ``failed(counters)``;
+* ``window_flops(run, counters)``: the model operations of the work the
+  window completed, for the whole-step share of peak (``*_mfu``);
+* optionally ``FAULTS``: faults of its own timed path by name, which
+  ``calibrate.py --faults`` takes before those of ``faults.py``.
 """
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import os
 import shutil
 import sys
@@ -15,35 +39,48 @@ import time
 from typing import Dict, List
 
 from bench.harness import checks as C
-from bench.harness import device, serve_cell, spec, train_cell
+from bench.harness import device, spec
 
-DRIVERS = {"train": train_cell, "serve": serve_cell}
 TRACE_DIR = os.path.join(spec.BENCH_DIR, ".trace")
+
+
+def driver(kind: str):
+    """The driver of a traffic mix's ``kind``."""
+    return importlib.import_module(f"bench.harness.{kind}_cell")
 
 
 @dataclasses.dataclass
 class Run:
     cell: spec.Cell
     seed: int
-    sizes: spec.Sizes
+    sizes: object                      # the model module's sizes
     arch: object                       # the program's ArchConfig
     peaks: Dict
     devices: List
+    model: object                      # the cell's model module
+    driver: object                     # the cell's driver module
+
+
+def make_run(cell: spec.Cell, seed: int, devices: List, peaks: Dict) -> Run:
+    model = spec.model_module(cell.config)
+    arch = getattr(model, "arch_config", spec.arch_config)(cell.config)
+    return Run(cell=cell, seed=seed, sizes=model.sizes(cell.config),
+               arch=arch, peaks=peaks, devices=devices, model=model,
+               driver=driver(cell.kind))
 
 
 def run_cell(cell: spec.Cell, *, seed: int, seconds: float, trace: bool,
              devices: List, peaks: Dict, t_start: float,
              control: bool = False) -> Dict:
-    run = Run(cell=cell, seed=seed, sizes=spec.sizes(cell.config),
-              arch=spec.arch_config(cell.config), peaks=peaks,
-              devices=devices)
-    drv = DRIVERS[cell.kind]
+    run = make_run(cell, seed, devices, peaks)
+    drv = run.driver
     built = drv.build(run)
     setup_s = time.perf_counter() - t_start
     _log(f"set-up {setup_s:.3f} s")
 
     if trace:
-        counters, summary = _traced_window(run, drv, built, seconds)
+        traced = min(seconds, float(cell.params.get("trace_seconds", seconds)))
+        counters, summary = _traced_window(run, drv, built, traced)
     else:
         counters, summary = drv.window(run, built, seconds), None
     dev = dict(device.describe(devices),

@@ -38,7 +38,7 @@ def build(run) -> Dict:
 
     c, cfg, cp, mix = run.sizes, run.arch, run.cell.params, run.cell.traffic
     key = W.base_key(run.seed)
-    params = W.init_params(c, key)
+    params = run.model.init_params(c, key)
     images = traffic.image_pool(mix, c, W.sub_key(key, 4))
     page, slots = int(cp["page_size"]), int(cp["n_slots"])
     longest = c.n_image_tokens + mix["prompt_tokens"]["max"] \
@@ -154,6 +154,21 @@ def end_to_end(counters: Dict) -> Dict[str, float]:
     return dict(serve_tokens_per_s=tokens / (end - t0))
 
 
+def window_flops(run, counters: Dict) -> float:
+    """Each admitted request's prefill over its image and prompt and each
+    token it decoded at its context, attention included."""
+    c, model = run.sizes, run.model
+    n = 0.0
+    for r in counters["served"]:
+        if not r.out:
+            continue
+        n += model.prefill_flops(c, len(r.tokens))
+        start = c.n_image_tokens + len(r.tokens)
+        n += sum(model.decode_flops(c, start + j + 1)
+                 for j in range(len(r.out) - 1))
+    return n
+
+
 def latencies(counters: Dict) -> Dict[str, np.ndarray]:
     """Milliseconds from each request's due time to its first token
     (``ttft``; one never answered waited until the close at least) and
@@ -230,10 +245,8 @@ def _gaps(logits: np.ndarray, tokens: np.ndarray,
 def _reference(run, a: Dict, lp: bool) -> np.ndarray:
     import jax.numpy as jnp
 
-    from bench.reference import model as R
-
     key = W.base_key(run.seed)
-    return np.asarray(R.serve_logits(
+    return np.asarray(run.model.serve_logits(
         run.sizes, key, jnp.asarray(a["img"]), jnp.asarray(a["prompt"]),
         jnp.asarray(a["plen"]), jnp.asarray(a["served"]), a["lb"], lp=lp))
 

@@ -5,6 +5,11 @@ Layout under ``bench/`` (one file per thing, found by the name that
 ``BENCHMARK.json`` gives it):
 
 * ``configs/<config>.json``   sizes of one model configuration, as run;
+  its optional ``"bench_model"`` names its model module;
+* ``models/<name>.py``        a family's weights, plain reference and
+  operation counts (``bench/models/__init__.py``);
+* ``harness/<kind>_cell.py``  the driver of a traffic mix's ``kind``
+  (``bench/harness/runner.py``);
 * ``traffic/<traffic>.json``  parameters of one traffic mix;
 * ``cells/<workload>.json``   the sizes of one cell (batch, slots, rate)
   and the limits of its correctness check;
@@ -74,40 +79,21 @@ def load_cell(workload: str, root: str = ROOT) -> Cell:
     )
 
 
-@dataclasses.dataclass(frozen=True)
-class Sizes:
-    """A configuration's sizes as the benchmark's own code reads them
-    (weights, reference, operation counts); hashable, so it can key a
-    jit cache."""
-
-    n_layers: int
-    d_model: int
-    n_heads: int
-    n_kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab_size: int
-    rope_theta: float
-    norm_eps: float
-    n_image_tokens: int
-    d_vision: int
-    d_connector: int
-    param_dtype: str
-    compute_dtype: str
-    cut_layer: int
-    quant_bits: int
-    clip_sigma: float
-    commit_alpha: float
-    learnable_codec: bool
+DEFAULT_MODEL = "dense_vlm"
 
 
-def sizes(config: Dict) -> Sizes:
-    s = config["split"]
-    kw = {f.name: config[f.name] for f in dataclasses.fields(Sizes)
-          if f.name in config}
-    return Sizes(cut_layer=s["cut_layer"], quant_bits=s["bits"],
-                 clip_sigma=s["clip_sigma"], commit_alpha=s["commit_alpha"],
-                 learnable_codec=s["learnable_codec"], **kw)
+def model_module(config: Dict):
+    """The model module a configuration names (``"bench_model"``), or the
+    dense split-VLM one: ``bench/models/<name>.py``."""
+    import importlib
+
+    return importlib.import_module(
+        "bench.models." + config.get("bench_model", DEFAULT_MODEL))
+
+
+def sizes(config: Dict):
+    """A configuration's sizes, as its model module reads them."""
+    return model_module(config).sizes(config)
 
 
 def arch_config(config: Dict):

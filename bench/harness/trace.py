@@ -126,6 +126,7 @@ class Summary:
     modules: List[Op]
     spans: List[Tuple[str, int, int]]        # host spans (name, start, end)
     kernels: Dict[Tuple[str, str], Kernel]   # (program, instruction)
+    hlo_texts: List[str] = dataclasses.field(default_factory=list)
 
     @property
     def window_s(self) -> float:
@@ -259,7 +260,8 @@ def reduce_profile(pd, hlo_texts: List[str], n_devices: int) -> Summary:
     for text in hlo_texts:
         kernels.update(kernels_in_hlo(text))
     return Summary(window=(lo, hi), n_devices=n_devices, ops=ops,
-                   modules=modules, spans=spans, kernels=kernels)
+                   modules=modules, spans=spans, kernels=kernels,
+                   hlo_texts=list(hlo_texts))
 
 
 def _name_programs(ops: List[Op], modules: List[Op]) -> None:

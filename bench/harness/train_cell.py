@@ -31,10 +31,10 @@ def build(run) -> Dict:
     from repro.optim import init_opt_state
     from repro.train import loop as train_loop
 
-    c, cfg, cp = run.sizes, run.arch, run.cell.params
+    c, cfg, cp, model = run.sizes, run.arch, run.cell.params, run.model
     key = W.base_key(run.seed)
     opt = _opt(cp)
-    params = W.init_params(c, key)
+    params = model.init_params(c, key)
     state = train_loop.TrainState(params=params,
                                   opt=init_opt_state(params, opt),
                                   step=jnp.zeros((), jnp.int32))
@@ -50,7 +50,7 @@ def build(run) -> Dict:
         lambda x: jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32)))), t))
     change = jax.jit(lambda p, k: norms(jax.tree_util.tree_map(
         lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32),
-        p, W.init_params(c, k))))
+        p, model.init_params(c, k))))
 
     n_check = int(cp["check_steps"])
     losses, grad_norms = [], None
@@ -62,8 +62,8 @@ def build(run) -> Dict:
                 lambda x: x / (1.0 - b1), state.opt["m"]))
     change_norms = change(state.params, key)
     readings = dict(losses=[float(x) for x in losses],
-                    grad_norms=_named(grad_norms),
-                    change_norms=_named(change_norms))
+                    grad_norms=named(grad_norms),
+                    change_norms=named(change_norms))
     return dict(compiled=compiled, state=state, batches=batches, rng=rng,
                 readings=readings)
 
@@ -73,46 +73,57 @@ def program_texts(run, built: Dict) -> list:
     return [built["compiled"].as_text()]
 
 
-def _named(tree) -> Dict[str, float]:
+def named(tree) -> Dict[str, float]:
+    """{leaf path: value} of a tree of scalars."""
     import jax
 
     return {jax.tree_util.keystr(p): float(v) for p, v in
             jax.tree_util.tree_flatten_with_path(tree)[0]}
 
 
-def window(run, built: Dict, seconds: float) -> Dict:
-    """Steps back to back for ``seconds``, with ``ahead_steps`` steps
-    dispatched ahead of the one the host waits for, so that the chip stays
-    fed while the host stands still.  Once the time is up nothing more is
-    sent; every step sent is waited for and counted, and the clock is read
-    after that wait.  Returns the counters of the window."""
+def drive(step, state, batches: list, start: int, ahead: int,
+          seconds: float):
+    """Steps back to back for ``seconds``, with ``ahead`` steps dispatched
+    ahead of the one the host waits for, so that the chip stays fed while
+    the host stands still.  ``step(state, batch)`` returns the new state
+    and the step's loss.  Once the time is up nothing more is sent; every
+    step sent is waited for and counted, and the clock is read after that
+    wait.  Returns (state, steps done, seconds)."""
     from collections import deque
 
     from jax.profiler import TraceAnnotation
 
-    compiled, state = built["compiled"], built["state"]
-    batches, rng = built["batches"], built["rng"]
     n_b = len(batches)
-    start = int(run.cell.params["check_steps"])
-    ahead = int(run.cell.params["ahead_steps"])
     t0 = time.perf_counter()
     sent, i = deque(), start
     while time.perf_counter() - t0 < seconds:
         with TraceAnnotation("bench.train_step"):
-            state, m = compiled(state, batches[i % n_b], rng)
+            state, loss = step(state, batches[i % n_b])
         i += 1
-        sent.append(m["loss"])
+        sent.append(loss)
         if len(sent) > ahead:
             with TraceAnnotation("bench.wait_step"):
                 sent.popleft().block_until_ready()
     with TraceAnnotation("bench.wait_step"):
         for loss in sent:
             loss.block_until_ready()
-    done = i - start
-    elapsed = time.perf_counter() - t0
-    built["state"] = state
-    tokens = done * traffic.tokens_per_batch(run.cell.traffic,
-                                             run.cell.params)
+    return state, i - start, time.perf_counter() - t0
+
+
+def window(run, built: Dict, seconds: float) -> Dict:
+    """The compiled step driven by ``drive`` from the first step after the
+    checked ones.  Returns the counters of the window."""
+    compiled, rng = built["compiled"], built["rng"]
+
+    def step(state, batch):
+        state, m = compiled(state, batch, rng)
+        return state, m["loss"]
+
+    cp = run.cell.params
+    built["state"], done, elapsed = drive(
+        step, built["state"], built["batches"], int(cp["check_steps"]),
+        int(cp["ahead_steps"]), seconds)
+    tokens = done * traffic.tokens_per_batch(run.cell.traffic, cp)
     return dict(steps=done, tokens=tokens, window_s=elapsed)
 
 
@@ -120,16 +131,22 @@ def end_to_end(counters: Dict) -> Dict[str, float]:
     return dict(train_tokens_per_s=counters["tokens"] / counters["window_s"])
 
 
-def reference_readings(run, lp: bool = False) -> Dict:
-    from bench.reference import model as R
+def window_flops(run, counters: Dict) -> float:
+    """Forward and backward of every step the window completed."""
+    return counters["steps"] * run.model.train_step_flops(
+        run.sizes, int(run.cell.params["batch"]),
+        int(run.cell.traffic["seq_len"]))
 
+
+def reference_readings(run, lp: bool = False) -> Dict:
     cp = run.cell.params
     key = W.base_key(run.seed)
     batches = traffic.train_batches(run.cell.traffic, cp, run.sizes,
                                     W.sub_key(key, 2))
     n = int(cp["check_steps"])
-    return R.train_reference(run.sizes, key, batches[:n], cp["optimizer"],
-                             n, int(cp["reference_row_block"]), lp=lp)
+    return run.model.train_reference(run.sizes, key, batches[:n],
+                                     cp["optimizer"], n,
+                                     int(cp["reference_row_block"]), lp=lp)
 
 
 def compare(prog: Dict, ref: Dict, cell_params: Dict) -> Dict[str, float]:
@@ -148,7 +165,8 @@ def check(run, built: Dict, counters: Dict) -> Dict[str, float]:
     prog = built["readings"]
     for k in ("state", "batches", "compiled"):
         built.pop(k, None)
-    built["reference"] = reference_readings(run)
+    if "reference" not in built:  # a fault run takes its seed's reference
+        built["reference"] = reference_readings(run)
     return compare(prog, built["reference"], run.cell.params)
 
 

@@ -15,7 +15,7 @@ KERNEL = "_paged_kernel"
 
 
 def read(ctx):
-    c = ctx.run.sizes
+    c, model = ctx.run.sizes, ctx.run.model
     calls = ctx.summary.kernel_ops(KERNEL)
     spent = sum(op.end - op.start for op, _ in calls) / 1e9
     if spent <= 0:
@@ -24,9 +24,9 @@ def read(ctx):
     for r in ctx.counters["served"]:
         start = c.n_image_tokens + len(r.tokens)
         for j in range(max(len(r.out) - 1, 0)):
-            w = F.paged_decode(c, start + j + 1)
-            fl += w["flops"] * c.n_layers
-            by += w["bytes"] * c.n_layers
+            w = model.paged_decode(c, start + j + 1)
+            fl += w["flops"]
+            by += w["bytes"]
     r = F.roofline_share(fl, by, spent, ctx.run.peaks)
     print(f"bench: paged_decode_roofline bound by {r['bound']}",
           file=sys.stderr)

@@ -7,7 +7,7 @@ import pytest
 
 from conftest import ROOT, tiny_config
 
-from bench.harness import spec
+from bench.harness import serve_cell, spec, train_cell
 from bench.harness import trace as T
 
 PEAKS = spec.peaks("TPU v5 lite", ROOT)
@@ -20,7 +20,9 @@ def _req(plen, n_out):
 
 def _ctx(ops=(), modules=(), kernels=None, served=(), stats=None):
     run = types.SimpleNamespace(sizes=spec.sizes(tiny_config()),
-                                peaks=PEAKS, devices=[None],
+                                model=spec.model_module(tiny_config()),
+                                driver=serve_cell, peaks=PEAKS,
+                                devices=[None],
                                 cell=types.SimpleNamespace(
                                     params={"batch": 4},
                                     traffic={"seq_len": 24}))
@@ -77,5 +79,6 @@ def test_idle_shares_and_mfu():
     ctx = _ctx(ops=ops, served=[_req(5, 4)])
     assert _read("train.device_idle_share", ctx) == pytest.approx(75.0)
     assert _read("serve.device_idle_share", ctx) == pytest.approx(75.0)
-    assert 0.0 < _read("train_mfu", ctx) < 100.0
     assert 0.0 < _read("serve_mfu", ctx) < 100.0
+    ctx.run.driver = train_cell
+    assert 0.0 < _read("train_mfu", ctx) < 100.0
