@@ -153,11 +153,14 @@ def decode_q8_pallas(qf, k_codes, v_codes, k_scale, v_scale, kpos, qpos, *,
 
 def decode_paged_pallas(qf, k_pool, v_pool, pos_pool, page_table, qpos, *,
                         window=None):
-    """Paged-pool decode (serving engine): the page table rides in as a
-    scalar-prefetch operand so pool pages are DMA'd straight from their
-    physical location — no gathered contiguous cache copy.  qf
-    (S, KH, G, D) pre-scaled; pools (P, pg, KH, D/Dv); page_table (S, npp)
-    with -1 for unallocated; qpos (S,).  Returns (S, KH, G, Dv) fp32."""
+    """Paged-pool decode (serving engine).  The grid is (S, nb): each step
+    reads a block of up to 32 pages (at most 512 positions, within a VMEM
+    budget; ``decode_kernel.paged_block``), straight from their physical
+    location — no gathered contiguous cache copy.  A slot reads only its
+    live pages (0 .. qpos // pg, those a window reaches); the others are
+    neither copied nor attended.  qf (S, KH, G, D) pre-scaled; pools
+    (P, pg, KH, D/Dv); page_table (S, npp) with -1 for unallocated; qpos
+    (S,).  Returns (S, KH, G, Dv) fp32."""
     return decode_kernel.decode_paged(
         qf, k_pool, v_pool, pos_pool, page_table.astype(jnp.int32),
         qpos.astype(jnp.int32), window=window, interpret=_interpret())

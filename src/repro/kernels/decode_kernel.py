@@ -95,22 +95,22 @@ def _finalize(o_ref, l_s, acc_s):
     o_ref[0] = acc_s[...] / jnp.maximum(l_s[...], 1e-30)
 
 
-def _attend_heads(q_ref, k_ref, v_ref, valid, m_s, l_s, acc_s,
-                  ks_ref=None, vs_ref=None):
+def _attend_heads(q_ref, k_of, v_of, valid, m_s, l_s, acc_s,
+                  ks_of=None, vs_of=None):
     """One online-softmax step for every KV head of the block.
 
-    q_ref (1, KH, G, D) pre-scaled queries; k_ref/v_ref (1, bL, KH, D/Dv)
-    cache blocks (int8 codes when ``ks_ref``/``vs_ref`` hold the (1, KH,
-    bL) absmax scales); valid (1, bL).  The V scales fold into p before
-    the dot, while the l normalizer keeps the unscaled p, matching the
-    reference softmax-then-scale order."""
-    for h in range(k_ref.shape[2]):
+    q_ref (1, KH, G, D) pre-scaled queries; ``k_of(h)``/``v_of(h)`` give
+    head h's (bL, D/Dv) keys and values (int8 codes when ``ks_of``/``vs_of``
+    give its (1, bL) absmax scales); valid (1, bL).  The V scales fold
+    into p before the dot, while the l normalizer keeps the unscaled p,
+    matching the reference softmax-then-scale order."""
+    for h in range(q_ref.shape[1]):
         q = q_ref[0, h]                           # (G, D)
-        k = k_ref[0, :, h, :].astype(q.dtype)     # (bL, D)
+        k = k_of(h).astype(q.dtype)               # (bL, D)
         s = jax.lax.dot_general(q, k, _TRANS_B,
                                 preferred_element_type=jnp.float32)
-        if ks_ref is not None:
-            s = s * ks_ref[0, h:h + 1, :]
+        if ks_of is not None:
+            s = s * ks_of(h)
         s = jnp.where(valid, s, _NEG_INF)
         m_prev = m_s[h]                           # (G, 1)
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -118,9 +118,9 @@ def _attend_heads(q_ref, k_ref, v_ref, valid, m_s, l_s, acc_s,
         corr = jnp.exp(m_prev - m_next)
         l_s[h] = l_s[h] * corr + jnp.sum(p, axis=1, keepdims=True)
         m_s[h] = m_next
-        if vs_ref is not None:
-            p = p * vs_ref[0, h:h + 1, :]
-        v = v_ref[0, :, h, :].astype(q.dtype)     # (bL, Dv)
+        if vs_of is not None:
+            p = p * vs_of(h)
+        v = v_of(h).astype(q.dtype)               # (bL, Dv)
         pv = jax.lax.dot_general(p.astype(q.dtype), v, _PLAIN,
                                  preferred_element_type=jnp.float32)
         acc_s[h] = acc_s[h] * corr + pv
@@ -158,8 +158,11 @@ def _decode_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, *rest, window,
 
     @pl.when(_any(valid))
     def _compute():
-        _attend_heads(q_ref, k_ref, v_ref, valid, m_s, l_s, acc_s,
-                      ks_ref, vs_ref)
+        scales = () if ks_ref is None else (
+            lambda h: ks_ref[0, h:h + 1, :], lambda h: vs_ref[0, h:h + 1, :])
+        _attend_heads(q_ref, lambda h: k_ref[0, :, h, :],
+                      lambda h: v_ref[0, :, h, :], valid, m_s, l_s, acc_s,
+                      *scales)
 
     @pl.when(j == nl - 1)
     def _fin():
@@ -222,46 +225,135 @@ def decode_q8(qf, k_codes, v_codes, k_scale, v_scale, kpos, qpos, *,
 #
 # The continuous-batching engine stores the KV cache as fixed-size pages
 # in a shared pool; each slot owns a page table mapping logical page j to
-# a physical pool page.  The page table and the slots' query positions
-# ride in as *scalar-prefetch* operands (PrefetchScalarGridSpec), so the
-# BlockSpec index maps read the table to DMA each slot's pages straight
-# out of the pool — no gathered contiguous copy of the cache ever exists.
-# Unallocated entries (-1) are clamped to physical page 0 (the engine's
-# reserved null page) for the DMA and masked out in-kernel via the
-# prefetched table, so whatever page 0 holds never contributes.  Grid:
-# (S, npp), page axis innermost; each step takes one (pg, KH, D) page
-# with every KV head, the same online-softmax sweep as the ring kernels.
-# A page block spans whole trailing axes, so every page size of two or
-# more compiles (:func:`paged_compiles`).
+# a physical pool page.  The kernel reads each slot's pages straight out
+# of the pool — no gathered contiguous copy of the cache ever exists.
+#
+# Grid: (S, nb), block axis innermost; a step reads a block of ``bp``
+# logical pages (:func:`paged_block`: the largest power of two with
+# bp * pg <= 512 positions, at most 32 pages and within a VMEM budget;
+# the table is padded with -1 to a multiple of bp).  Each page of the
+# block is a pipelined operand of its own (K, V, positions and, for the
+# int8 pool, the scales), so the grid pipeline copies the next step's
+# pages while this step computes, across slot boundaries too; in VMEM
+# the block's pages are concatenated into one (bp * pg)-long sweep per
+# KV head.
+#
+# A slot reads only its live pages: logical pages 0 .. qpos // pg (with
+# a window, from the page holding qpos - window + 1) that the table
+# allocates.  The wrapper turns table and qpos into a *page plan*
+# (:func:`_page_plan`), the scalar-prefetch operand the index maps read:
+# per (slot, logical page), a live page's physical page, or ``~p`` for a
+# skipped page, with p the page its operand already holds — the block
+# index does not change, so the pipeline copies nothing for it.  A
+# skipped page's VMEM therefore holds another page's data; the kernel
+# masks it by the plan (its positions read as -1), never by that data.
+# Inside live pages the position pool masks as before (written, causal,
+# in window).  An inactive slot (qpos -1) reads no page and returns
+# exact zeros.
+#
+# The pages are grid operands rather than manual copies of pools
+# passed in HBM (``memory_space=pl.ANY``): Mosaic copies by hand only
+# whole (sublane, lane) tiles of the two minor axes, which a (pg, KH, D)
+# page with KH = 5 or D = 64, or a (1, pg) position row, is not; the grid
+# pipeline's copies take any block that spans whole axes, so every page
+# size of two or more compiles (:func:`paged_compiles`).
 
-def _pt_phys(pt_ref, s, j):
-    """Clamped physical page for (slot s, logical page j)."""
-    return jnp.maximum(pt_ref[s, j], 0)
+PAGED_BLOCK_POSITIONS = 512
+PAGED_BLOCK_PAGES = 32
+PAGED_VMEM_BUDGET = 8 << 20  # one block's K and V pages, double-buffered
 
 
-def _paged_kernel(pt_ref, qpos_ref, q_ref, k_ref, v_ref, pos_ref, *rest,
-                  window, npp, quantized):
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_s, l_s, acc_s = rest
-    else:
-        ks_ref = vs_ref = None
-        o_ref, m_s, l_s, acc_s = rest
+def _tile_bytes(rows, cols, dtype):
+    """VMEM bytes of a (rows, cols) array in (sublane, 128-lane) tiles."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sub = 8 * (4 // itemsize)  # 8 rows a tile at 32 bits, 16 at 16, 32 at 8
+    return -(-rows // sub) * sub * (-(-cols // 128) * 128) * itemsize
+
+
+def paged_block(npp: int, page_size: int, position_bytes: int) -> int:
+    """Pages a paged grid step reads: the largest power of two ``bp`` with
+    bp <= npp, bp * page_size <= PAGED_BLOCK_POSITIONS, bp <=
+    PAGED_BLOCK_PAGES (each page is an operand of its own) and the
+    block's pages, double-buffered, within PAGED_VMEM_BUDGET
+    (``position_bytes`` is one position's K and V in VMEM tiles)."""
+    def fits(n):
+        return (n <= min(npp, PAGED_BLOCK_PAGES)
+                and n * page_size <= PAGED_BLOCK_POSITIONS
+                and 2 * n * page_size * position_bytes <= PAGED_VMEM_BUDGET)
+
+    bp = 1
+    while fits(2 * bp):
+        bp *= 2
+    return bp
+
+
+def _page_plan(page_table, qpos, *, page_size, bp, window):
+    """(S * npp,) int32 page plan in grid order: entry s * npp + j is slot
+    s's logical page j.  A live page (allocated, at or before qpos's page,
+    reached by the window) gives its physical page; a skipped one gives
+    ``~p``, p the page its operand read last (before its first live page,
+    the one it reads first; the last step's page if it reads none)."""
+    s, npp = page_table.shape
+    qp = qpos[:, None]
+    lp = jnp.arange(npp)[None, :]
+    first = 0 if window is None else \
+        jnp.maximum(qp - window + 1, 0) // page_size
+    live = ((qp >= 0) & (lp >= first) & (lp <= qp // page_size)
+            & (page_table >= 0))
+    steps = s * npp // bp                 # grid steps; operand i = j % bp
+    live = live.reshape(steps, bp)
+    phys = jnp.maximum(page_table, 0).reshape(steps, bp)
+    t = jnp.arange(steps)[:, None]
+    last_read = jax.lax.cummax(jnp.where(live, t, -1), axis=0)
+    next_read = jax.lax.cummin(jnp.where(live, t, steps - 1), axis=0,
+                               reverse=True)
+    held = jnp.take_along_axis(
+        phys, jnp.where(last_read >= 0, last_read, next_read), axis=0)
+    return jnp.where(live, phys, ~held).reshape(-1)
+
+
+def _held(entry):
+    """Physical page a plan entry keeps in its operand's VMEM."""
+    return jnp.where(entry >= 0, entry, ~entry)
+
+
+def _paged_kernel(plan_ref, qpos_ref, q_ref, *refs, window, bp, nb,
+                  quantized):
+    n_in = (5 if quantized else 3) * bp
+    k_refs, v_refs, pos_refs = refs[:bp], refs[bp:2 * bp], refs[2 * bp:3 * bp]
+    ks_refs, vs_refs = refs[3 * bp:4 * bp], refs[4 * bp:n_in]
+    o_ref, m_s, l_s, acc_s = refs[n_in:]
     s_idx = pl.program_id(0)
-    j = pl.program_id(1)
+    b = pl.program_id(1)
+    base = (s_idx * nb + b) * bp
+    live = [plan_ref[base + i] >= 0 for i in range(bp)]
 
-    @pl.when(j == 0)
+    @pl.when(b == 0)
     def _init():
         _init_state(m_s, l_s, acc_s)
 
-    valid = _valid(pos_ref[0], qpos_ref[s_idx], window) \
-        & (pt_ref[s_idx, j] >= 0)                  # (1, pg)
+    @pl.when(functools.reduce(jnp.logical_or, live))
+    def _block():
+        kp = jnp.concatenate([jnp.where(live[i], pos_refs[i][0], -1)
+                              for i in range(bp)], axis=1)  # (1, bp * pg)
+        valid = _valid(kp, qpos_ref[s_idx], window)
 
-    @pl.when(_any(valid))
-    def _compute():
-        _attend_heads(q_ref, k_ref, v_ref, valid, m_s, l_s, acc_s,
-                      ks_ref, vs_ref)
+        def rows(pages):        # head h's (bp * pg, D) sweep of the block
+            return lambda h: jnp.concatenate(
+                [r[0, :, h, :].astype(q_ref.dtype) for r in pages], axis=0)
 
-    @pl.when(j == npp - 1)
+        def lanes(pages):       # head h's (1, bp * pg) scales
+            return lambda h: jnp.concatenate(
+                [r[0, h:h + 1, :] for r in pages], axis=1)
+
+        scales = (lanes(ks_refs), lanes(vs_refs)) if quantized else ()
+
+        @pl.when(_any(valid))
+        def _compute():
+            _attend_heads(q_ref, rows(k_refs), rows(v_refs), valid,
+                          m_s, l_s, acc_s, *scales)
+
+    @pl.when(b == nb - 1)
     def _fin():
         _finalize(o_ref, l_s, acc_s)
 
@@ -271,32 +363,43 @@ def _paged_call(qf, k_pool, v_pool, scales, pos_pool, page_table, qpos, *,
     s, kh, g, d = qf.shape
     n_pages, pg = k_pool.shape[:2]
     dv = v_pool.shape[-1]
-    npp = page_table.shape[1]
-    page_map = lambda s_, j, pt, qp: (_pt_phys(pt, s_, j), 0, 0, 0)
-    row_map = lambda s_, j, pt, qp: (_pt_phys(pt, s_, j), 0, 0)
-    in_specs = [
-        pl.BlockSpec((1, kh, g, d), lambda s_, j, pt, qp: (s_, 0, 0, 0)),
-        pl.BlockSpec((1, pg, kh, d), page_map),
-        pl.BlockSpec((1, pg, kh, dv), page_map),
-        pl.BlockSpec((1, 1, pg), row_map),
-    ]
-    in_specs += [pl.BlockSpec((1, kh, pg), row_map)] * len(scales)
+    bp = paged_block(page_table.shape[1], pg,
+                     _tile_bytes(kh, d, k_pool.dtype)
+                     + _tile_bytes(kh, dv, v_pool.dtype))
+    pad = -page_table.shape[1] % bp
+    page_table = jnp.pad(page_table, ((0, 0), (0, pad)), constant_values=-1)
+    nb = page_table.shape[1] // bp
+    plan = _page_plan(page_table, qpos, page_size=pg, bp=bp, window=window)
+
+    def page(i, rank):
+        return lambda s_, b, plan_, qp: (
+            (_held(plan_[(s_ * nb + b) * bp + i]),) + (0,) * (rank - 1))
+
+    pools = [(k_pool, (1, pg, kh, d)), (v_pool, (1, pg, kh, dv)),
+             (pos_pool.reshape(n_pages, 1, pg), (1, 1, pg))]
+    pools += [(sc, (1, kh, pg)) for sc in scales]
+    in_specs = [pl.BlockSpec((1, kh, g, d),
+                             lambda s_, b, plan_, qp: (s_, 0, 0, 0))]
+    operands = [qf]
+    for pool, block in pools:
+        in_specs += [pl.BlockSpec(block, page(i, len(block)))
+                     for i in range(bp)]
+        operands += [pool] * bp
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(s, npp),
+        grid=(s, nb),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, kh, g, dv),
-                               lambda s_, j, pt, qp: (s_, 0, 0, 0)),
+                               lambda s_, b, plan_, qp: (s_, 0, 0, 0)),
         scratch_shapes=_scratch(kh, g, dv),
     )
-    kernel = functools.partial(_paged_kernel, window=window, npp=npp,
+    kernel = functools.partial(_paged_kernel, window=window, bp=bp, nb=nb,
                                quantized=bool(scales))
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, kh, g, dv), jnp.float32),
         interpret=interpret,
-    )(page_table, qpos, qf, k_pool, v_pool,
-      pos_pool.reshape(n_pages, 1, pg), *scales)
+    )(plan, qpos, *operands)
 
 
 def decode_paged(qf, k_pool, v_pool, pos_pool, page_table, qpos, *,

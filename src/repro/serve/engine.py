@@ -152,6 +152,7 @@ class ServeEngine:
                           prefill_rows=0, prefill_positions=0,
                           prefill_real_positions=0,
                           prefill_fetch_bytes=0, compiles=0,
+                          tick_pages_read=0, tick_page_slots=0,
                           page_table_buckets=set())
         self._lowerings0 = compile_cache.lowerings()
         if self.wq_report is not None:
@@ -310,8 +311,14 @@ class ServeEngine:
         s = self.scheduler.n_slots
         npp = paged.next_pow2(max(r.qpos // pg + 1 for r in active))
         self.stats["page_table_buckets"].add(npp)
+        # the paged kernel reads each active slot's pages up to its qpos;
+        # its grid spans every slot's whole bucket
+        pages_read = sum(r.qpos // pg + 1 for r in active)
+        self.stats["tick_pages_read"] += pages_read
+        self.stats["tick_page_slots"] += s * npp
         with TraceAnnotation("engine.tick.inputs", active=len(active),
-                             npp=npp):
+                             npp=npp, pages_read=pages_read,
+                             page_slots=s * npp):
             tokens = np.zeros((s, 1), np.int32)
             qpos = np.full((s,), -1, np.int32)
             page_table = np.full((s, npp), -1, np.int32)

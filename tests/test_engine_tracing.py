@@ -25,7 +25,7 @@ SPANS = {
     "engine.wire": ("wire_bytes",),
     "engine.prefill.launch": (),
     "engine.prefill.fetch": ("bytes",),
-    "engine.tick.inputs": ("active", "npp"),
+    "engine.tick.inputs": ("active", "npp", "pages_read", "page_slots"),
     "engine.tick.launch": (),
     "engine.tick.fetch": (),
     "engine.pick": (),
@@ -133,6 +133,19 @@ def test_prefill_position_counters_by_hand(traced):
     assert st["prefill_rows"] == 4
     assert st["prefill_positions"] == 4 * 32
     assert st["prefill_real_positions"] == 3 * 16 + 5 + 7 + 9
+
+
+def test_tick_page_counters_by_hand(traced):
+    """After the prefill the requests decode from positions 16 + 5, 16 + 7
+    and 16 + 9, for 1, 2 and 3 ticks.  Pages of 8: the three ticks read
+    (3 + 3 + 4) + (4 + 4) + 4 = 22 pages, each in a bucket of 4 pages
+    (27 // 8 + 1 = 4 at most) over 4 slots, so the grid spans 3 * 16."""
+    st, events = traced["stats"], traced["events"]
+    assert st["tick_pages_read"] == 22
+    assert st["tick_page_slots"] == 3 * SLOTS * 4
+    ticks = events["engine.tick.inputs"]
+    assert [d["pages_read"] for _, _, d in ticks] == [10, 8, 4]
+    assert [d["page_slots"] for _, _, d in ticks] == [SLOTS * 4] * 3
 
 
 def test_prefill_fetch_bytes_by_hand(traced):

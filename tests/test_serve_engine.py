@@ -10,7 +10,7 @@ import pytest
 from repro.configs import get_config
 from repro.core import QuantConfig, SplitConfig
 from repro.core.split import WireLink
-from repro.kernels import attention_ops, attention_ref
+from repro.kernels import attention_ops, attention_ref, decode_kernel
 from repro.models import transformer as tf
 from repro.models.layers import attention as attn_mod
 from repro.serve import decode as sd
@@ -132,6 +132,93 @@ def test_decode_paged_q8_pallas_matches_ref(window):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
     assert np.all(np.asarray(out)[2] == 0.0)
+
+
+# the block kernel: (page size, table width, qpos of each slot, window);
+# slot 0 is the longest, slot 1 ends mid-page and mid-block after it (the
+# block's skipped pages hold slot 0's data), slot 2 is inactive, and
+# every table ends in -1 entries
+PAGED_BLOCK_CASES = {
+    "several_blocks": (64, 32, (1500, 300, -1, 10), None),  # 8-page blocks
+    "window_skips_blocks": (64, 32, (1500, 300, -1, 10), 200),
+    "table_under_a_block": (64, 4, (200, 63, -1, 64), None),
+    "page5": (5, 64, (250, 77, -1, 4), None),               # 32-page blocks
+}
+
+
+def _paged_blocks_fixture(pg, npp, qpos, kh=2, g=2, d=16):
+    """A pool as the engine leaves it: each active slot holds its logical
+    pages up to two past qpos's page (positions j * pg + r, those after
+    qpos masked causally), the inactive slot keeps two pages, and pages
+    are scattered at random.  Slot 0 has a hole of unwritten positions,
+    and its last three pages hold positions 0 .. pg - 1, as a reused
+    page's stale rows would: the blocks' skipped pages that slot 1 leaves
+    then hold positions slot 1 would attend if it read them."""
+    rng = np.random.default_rng(0)
+    n_live = [min(q // pg + 3, npp) if q >= 0 else 2 for q in qpos]
+    n_pages = 1 + sum(n_live)
+    phys = iter(1 + rng.permutation(n_pages - 1))
+    pt = np.full((len(qpos), npp), -1, np.int32)
+    pos = np.full((n_pages, pg), -1, np.int32)
+    for i, n in enumerate(n_live):
+        for j in range(n):
+            pt[i, j] = next(phys)
+            pos[pt[i, j]] = np.arange(j * pg, (j + 1) * pg)
+    for j in range(qpos[0] // pg - 2, qpos[0] // pg + 1):
+        pos[pt[0, j]] = np.arange(pg)
+    pos[pt[0, 1], 1:4] = -1
+    qf = jnp.asarray(rng.normal(size=(len(qpos), kh, g, d)),
+                     jnp.float32) / np.sqrt(d)
+    return (rng, n_pages, kh, d, jnp.asarray(pt),
+            jnp.asarray(qpos, jnp.int32), jnp.asarray(pos), qf)
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+@pytest.mark.parametrize("case", sorted(PAGED_BLOCK_CASES))
+def test_decode_paged_blocks_match_ref(case, pool):
+    pg, npp, qpos, window = PAGED_BLOCK_CASES[case]
+    rng, p, kh, d, pt, qpos, pos, qf = _paged_blocks_fixture(pg, npp, qpos)
+    if pool == "bf16":
+        qf = qf.astype(jnp.bfloat16)
+        k = jnp.asarray(rng.normal(size=(p, pg, kh, d)), jnp.bfloat16)
+        v = jnp.asarray(rng.normal(size=(p, pg, kh, d)), jnp.bfloat16)
+        f32 = lambda x: x.astype(jnp.float32)
+        ref = attention_ref.decode_attention_paged_ref(
+            f32(qf), f32(k), f32(v), pos, pt, qpos, window=window)
+        out = attention_ops.decode_paged_pallas(qf, k, v, pos, pt, qpos,
+                                                window=window)
+        tol = 2e-2  # p rounds to bf16 before the value dot
+    else:
+        kc = jnp.asarray(rng.integers(-127, 128, (p, pg, kh, d)), jnp.int8)
+        vc = jnp.asarray(rng.integers(-127, 128, (p, pg, kh, d)), jnp.int8)
+        ks = jnp.asarray(rng.uniform(0.01, 0.1, (p, pg, kh)), jnp.float16)
+        vs = jnp.asarray(rng.uniform(0.01, 0.1, (p, pg, kh)), jnp.float16)
+        ref = attention_ref.decode_attention_paged_q8_ref(
+            qf, kc, vc, ks, vs, pos, pt, qpos, window=window)
+        out = attention_ops.decode_paged_q8_pallas(
+            qf, kc, vc, ks, vs, pos, pt, qpos, window=window)
+        tol = 2e-4
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=tol, atol=tol)
+    assert np.all(np.asarray(out)[2] == 0.0)
+
+
+def test_page_plan_copies_only_live_pages():
+    """Each page operand's block index changes only where it reads a live
+    page, so the grid pipeline copies exactly the live pages (after the
+    first step, which copies whatever each operand starts on)."""
+    pg, npp, qpos, window = PAGED_BLOCK_CASES["several_blocks"]
+    _, _, _, _, pt, qpos, _, _ = _paged_blocks_fixture(pg, npp, qpos)
+    bp = 8
+    plan = np.asarray(decode_kernel._page_plan(
+        pt, qpos, page_size=pg, bp=bp, window=window)).reshape(-1, bp)
+    live = plan >= 0
+    held = np.where(live, plan, ~plan)
+    # live: slot 0 pages 0..23, slot 1 pages 0..4, slot 3 page 0
+    assert live.sum() == 24 + 5 + 1
+    assert np.array_equal(held[live], np.asarray(pt).reshape(-1, bp)[live])
+    changed = np.vstack([np.zeros((1, bp), bool), held[1:] != held[:-1]])
+    assert not np.any(changed & ~live)
 
 
 def test_paged_ref_equals_contiguous_ref_on_gathered_cache():

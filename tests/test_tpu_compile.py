@@ -1,5 +1,6 @@
 """Compile guards: every Pallas kernel of the main path, compiled for a
-described (not attached) TPU v5e chip at tinyllava's published widths.
+described (not attached) TPU v5e chip at tinyllava's published widths
+(the paged decode kernel at llava-next-34b's serving widths too).
 
 Interpret-mode parity tests cannot see the TPU tiling rules (block
 shapes, dtype casts, vector layouts); the v5e compiler can, without a
@@ -27,6 +28,10 @@ H, KH, D = CFG.n_heads, CFG.n_kv_heads, CFG.head_dim
 G = H // KH
 B, S = 8, 1024          # train/prefill batch and sequence of the smoke run
 PAGE, N_PAGES, NPP = 16, 512, 64
+# llava-next-34b as its offline serving cell runs it: 4 slots, a
+# 256-page table bucket, pages of 16
+BIG = get_config("llava-next-34b")
+BIG_SLOTS, BIG_N_PAGES, BIG_NPP = 4, 1024, 256
 
 
 @pytest.fixture(scope="module")
@@ -77,17 +82,18 @@ def _ring(bits):
             [q, codes, codes, scale, scale] + pos)
 
 
-def _paged(bits, page=PAGE):
-    q = ((B, KH, G, D), jnp.bfloat16)
-    tail = [((N_PAGES, page), jnp.int32), ((B, NPP), jnp.int32),
-            ((B,), jnp.int32)]
+def _paged(bits, page=PAGE, cfg=CFG, b=B, n_pages=N_PAGES, npp=NPP):
+    kh, d = cfg.n_kv_heads, cfg.head_dim
+    q = ((b, kh, cfg.n_heads // kh, d), jnp.bfloat16)
+    tail = [((n_pages, page), jnp.int32), ((b, npp), jnp.int32),
+            ((b,), jnp.int32)]
     if bits == 16:
-        pool = ((N_PAGES, page, KH, D), jnp.bfloat16)
+        pool = ((n_pages, page, kh, d), jnp.bfloat16)
         return (functools.partial(decode_kernel.decode_paged, window=None,
                                   interpret=False),
                 [q, pool, pool] + tail)
-    codes = ((N_PAGES, page, KH, D), jnp.int8)
-    scale = ((N_PAGES, KH, page), jnp.float32)
+    codes = ((n_pages, page, kh, d), jnp.int8)
+    scale = ((n_pages, kh, page), jnp.float32)
     return (functools.partial(decode_kernel.decode_paged_q8, window=None,
                               interpret=False),
             [q, codes, codes, scale, scale] + tail)
@@ -140,6 +146,10 @@ CASES = {
     # odd pages compile too (decode_kernel.paged_compiles)
     "decode_paged_bf16_page5": lambda: _paged(16, 5),
     "decode_paged_int8_page5": lambda: _paged(8, 5),
+    "decode_paged_bf16_llava34b": lambda: _paged(
+        16, cfg=BIG, b=BIG_SLOTS, n_pages=BIG_N_PAGES, npp=BIG_NPP),
+    "decode_paged_int8_llava34b": lambda: _paged(
+        8, cfg=BIG, b=BIG_SLOTS, n_pages=BIG_N_PAGES, npp=BIG_NPP),
     "rdfsq2_encode": lambda: _rdfsq("encode"),
     "rdfsq2_decode": lambda: _rdfsq("decode"),
     "nf4_encode": lambda: _nf("encode"),
